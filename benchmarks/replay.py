@@ -195,7 +195,7 @@ def main() -> None:
         rows = run(workload=args.workload, workload_out=args.workload_out,
                    analyze_out=args.analyze_out,
                    max_records=args.max_records)
-    except Exception as e:   # mirror benchmarks.run: fail loud, emit doc
+    except Exception as e:   # mirror benchmarks.run: emit the doc, exit 1
         print(f"replay/ERROR,,{type(e).__name__}:{e}")
         doc["suites"]["replay"] = {"error": f"{type(e).__name__}:{e}"}
         rows = []
@@ -209,6 +209,8 @@ def main() -> None:
         with open(args.json, "w") as f:
             json.dump(doc, f, indent=2, sort_keys=True)
         print(f"wrote {args.json}", file=sys.stderr)
+    if "error" in doc["suites"].get("replay", {}):
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -11,7 +11,9 @@ is not a latency).  Roofline terms come from the dry-run artifacts
 fixtures for CI smoke runs; ``--json PATH`` additionally writes all rows
 (plus per-suite wall time and errors) as a JSON document — the CI
 workflow uploads it as the ``BENCH_smoke.json`` artifact so the perf
-trajectory accumulates across commits.
+trajectory accumulates across commits.  A suite that raises prints an
+``<suite>/ERROR`` row, the remaining suites still run and the JSON
+document is still written, and the command exits 1.
 """
 from __future__ import annotations
 
@@ -87,6 +89,9 @@ def main() -> None:
     args = ap.parse_args()
     if args.smoke:
         os.environ["BENCH_SMOKE"] = "1"
+    # the suites import jax lazily; place its compile cache first
+    from repro.launch.env import use_compile_cache
+    use_compile_cache()
     picks = args.only.split(",") if args.only else list(SUITES)
     doc = {"smoke": bool(args.smoke), "suites": {}, "rows": {}}
     print("name,us_per_call,derived")
@@ -112,6 +117,9 @@ def main() -> None:
         with open(args.json, "w") as f:
             json.dump(doc, f, indent=2, sort_keys=True)
         print(f"wrote {args.json}", file=sys.stderr)
+    failed = [n for n, rec in doc["suites"].items() if "error" in rec]
+    if failed:
+        sys.exit(f"suites failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -437,7 +437,7 @@ def main() -> None:
     t0 = time.time()
     try:
         rows = run()
-    except Exception as e:   # mirror benchmarks.run: fail loud, emit doc
+    except Exception as e:   # mirror benchmarks.run: emit the doc, exit 1
         print(f"serving/ERROR,,{type(e).__name__}:{e}")
         doc["suites"]["serving"] = {"error": f"{type(e).__name__}:{e}"}
         rows = []
@@ -451,6 +451,8 @@ def main() -> None:
         with open(args.json, "w") as f:
             json.dump(doc, f, indent=2, sort_keys=True)
         print(f"wrote {args.json}", file=sys.stderr)
+    if "error" in doc["suites"].get("serving", {}):
+        sys.exit(1)
 
 
 if __name__ == "__main__":

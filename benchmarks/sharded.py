@@ -10,10 +10,13 @@ the sharded row partition was built for.  Rows:
     sharded/dense/devices{d}/supersteps     sharded supersteps executed
     sharded/dense/scaling_vs_1dev/x{d}      t(1 device) / t(d devices)
 
-On a CPU host the forced devices share the same cores, so the scaling
-column measures partitioning overhead rather than speedup — the row
-exists so the CI artifact tracks the trajectory and a TPU run slots in
-unchanged.  ``--smoke`` (or BENCH_SMOKE=1) shrinks the fixture.
+This is a CPU rehearsal of the sharded path by design: every child sets
+``JAX_PLATFORMS=cpu`` and forces its devices on the host, so it never
+competes for an accelerator the parent may hold.  The forced devices
+share the same cores, so the scaling column measures partitioning
+overhead, not speedup.  On a TPU the sharded path is driven by
+``python chip_smoke.py --four-chips``.  ``--smoke`` (or BENCH_SMOKE=1)
+shrinks the fixture.
 
     PYTHONPATH=src python -m benchmarks.sharded [--smoke]
 """
@@ -29,7 +32,7 @@ DEVICE_COUNTS = (1, 2, 4, 8)
 _CHILD = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={devices}"
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"
 import json, time
 import numpy as np
 from repro.core.engines import Query, make_engine

@@ -66,11 +66,15 @@ _ap.add_argument("--explain", action="store_true",
                       "ANALYZE (plan + per-superstep timeline) report for "
                       "one representative request")
 ARGS = _ap.parse_args()
+# repro.launch.env imports no jax: both calls must precede the first jax
+# import
+from repro.launch.env import force_host_devices, use_compile_cache
+
+use_compile_cache()
 if ARGS.force_host_devices:
-    # per-flag setdefault (repro.launch.env imports no jax): appending to
-    # XLA_FLAGS by hand here used to duplicate the flag on every
-    # invocation that inherited a non-empty XLA_FLAGS
-    from repro.launch.env import force_host_devices
+    # per-flag setdefault: appending to XLA_FLAGS by hand here used to
+    # duplicate the flag on every invocation that inherited a non-empty
+    # XLA_FLAGS
     force_host_devices(ARGS.force_host_devices)
 
 import numpy as np

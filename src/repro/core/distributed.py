@@ -56,25 +56,12 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def _resolve_shard_map():
-    """jax.shard_map graduated from jax.experimental between releases;
-    accept either spelling so the sharded BFS runs on old and new jax."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map
-    from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
 def _shard_map(f, mesh, in_specs, out_specs):
-    """shard_map with replication checking off (required for pallas_call
-    bodies, which have no replication rule); falls back to the plain
-    spelling on jax versions without the knob."""
-    sm = _resolve_shard_map()
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-    except TypeError:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    """``jax.shard_map`` with varying-manual-axes checking off: a
+    ``pallas_call`` body declares no ``vma`` on its output shapes, which
+    the check requires."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def resolve_mesh(

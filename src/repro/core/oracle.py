@@ -31,24 +31,33 @@ def _resolve(graph: LabeledGraph):
     return graph.resolve_lit
 
 
+def completed_out_edges(
+        graph: LabeledGraph) -> Dict[int, List[Tuple[int, int]]]:
+    """node u -> [(label, v)] over G ∪ Ĝ: the forward adjacency the
+    product BFS walks."""
+    out_edges: Dict[int, List[Tuple[int, int]]] = defaultdict(list)
+    for p, edges in _completed_adj(graph).items():
+        for u, v in edges:
+            out_edges[u].append((p, v))
+    return out_edges
+
+
 def eval_oracle(
     graph: LabeledGraph,
     expr: str,
     subject: Optional[int] = None,
     obj: Optional[int] = None,
+    out_edges: Optional[Dict[int, List[Tuple[int, int]]]] = None,
 ) -> Set[Tuple[int, int]]:
     """Evaluate the 2RPQ (subject, expr, obj) with (None = variable).
-    Returns all (s, o) pairs, including zero-length eps matches."""
+    Returns all (s, o) pairs, including zero-length eps matches.
+    ``out_edges`` is :func:`completed_out_edges` of ``graph``, passed by
+    callers that ask many queries of one large graph."""
     ast = rx.parse(expr)
     g = Glushkov.from_ast(ast, _resolve(graph))
-    adj = _completed_adj(graph)
     V = graph.num_nodes
-
-    # forward adjacency per (node) with labels, for product BFS
-    out_edges: Dict[int, List[Tuple[int, int]]] = defaultdict(list)  # u -> [(p, v)]
-    for p, edges in adj.items():
-        for u, v in edges:
-            out_edges[u].append((p, v))
+    if out_edges is None:
+        out_edges = completed_out_edges(graph)
 
     # NFA transitions: from state i (bit i), by label c, to states
     # follow_mask[i] & B[c]

@@ -791,11 +791,8 @@ class RingRPQ(dl.LiveUpdateEngine):
                 # through the mesh on any backend
                 self._auto_threshold = 64.0
                 return self._auto_threshold
-            try:
-                import jax
-                on_tpu = jax.default_backend() == "tpu"
-            except Exception:
-                on_tpu = False
+            import jax
+            on_tpu = jax.default_backend() == "tpu"
             # interpret-mode Pallas on the host loses to the byte-split
             # tables at any size; on TPU the kernel pays off quickly
             self._auto_threshold = 64.0 if on_tpu else float("inf")

@@ -1,9 +1,9 @@
 """Pallas TPU kernels for the paper's hot loops.
 
-:mod:`.ops` is the public API — jitted wrappers that resolve interpret
-mode once from the backend; the sibling modules hold the raw
-``pallas_call`` bodies (suffixed ``_pallas`` so the wrapper names are
-never shadowed).  The package re-exports the ``ops`` entry points, so
+:mod:`.ops` is the public API — jitted wrappers that compile the
+kernels on a TPU backend and interpret them on any other; the sibling
+modules hold the raw ``pallas_call`` bodies (suffixed ``_pallas`` so
+the wrapper names are never shadowed).  The package re-exports the ``ops`` entry points, so
 ``from repro.kernels import nfa_step`` is the supported spelling.
 
 ``PALLAS_KERNELS`` names the kernel-backed entry points: the precise

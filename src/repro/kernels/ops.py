@@ -1,8 +1,9 @@
 """Jitted public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels run in ``interpret=True`` mode; on a
-real TPU backend they compile to Mosaic.  ``interpret`` is resolved once
-from the default backend.
+On a TPU backend the kernels compile to Mosaic; on any other backend
+(CPU tests and rehearsals) they run in ``interpret=True`` mode.
+``interpret`` is resolved from the default backend at every call, so
+nothing fixed at import time can route a TPU process to the interpreter.
 """
 from __future__ import annotations
 
@@ -16,7 +17,9 @@ from . import nfa_step as _nfa
 from . import rank_popcount as _rank
 from . import segment_or as _seg
 
-_INTERPRET = jax.default_backend() != "tpu"
+
+def _interpret() -> bool:
+    return jax.default_backend() != "tpu"
 
 
 def pack_bits(planes: np.ndarray) -> np.ndarray:
@@ -43,12 +46,12 @@ def unpack_bits(packed: np.ndarray, S: int) -> np.ndarray:
 def nfa_step(X, bwd):
     """Bit-parallel reverse Glushkov step: Y = T'[X] (packed)."""
     return _nfa.nfa_step_pallas(jnp.asarray(X), jnp.asarray(bwd),
-                                interpret=_INTERPRET)
+                                interpret=_interpret())
 
 
 def superblock_popcounts(words):
     return _rank.superblock_popcounts_pallas(jnp.asarray(words),
-                                             interpret=_INTERPRET)
+                                             interpret=_interpret())
 
 
 def build_rank_directory(words):
@@ -81,7 +84,7 @@ def rank1(words, directory, i):
         jnp.where(rel == 0, partial, jnp.uint32(0)),
     )
     bases = directory[sb]
-    return _rank.rank_window(windows, masks, bases, interpret=_INTERPRET)
+    return _rank.rank_window(windows, masks, bases, interpret=_interpret())
 
 
 def segment_or(vals, seg_ids, num_segments: int):
@@ -93,7 +96,7 @@ def segment_or(vals, seg_ids, num_segments: int):
     flags = jnp.concatenate(
         [jnp.ones(1, jnp.int32), (seg_ids[1:] != seg_ids[:-1]).astype(jnp.int32)]
     )
-    scanned = _seg.segmented_or_scan(vals, flags, interpret=_INTERPRET)
+    scanned = _seg.segmented_or_scan(vals, flags, interpret=_interpret())
 
     # ---- stitch tile carries ----
     T = _seg.TILE_E
