@@ -1,0 +1,25 @@
+"""Published peaks of the chips the benchmark runs on, keyed by JAX's
+``device_kind``.  A device missing from the table is an error, never a
+default.
+
+Source: Google Cloud documentation, "TPU v5e" (system architecture):
+197 TFLOP/s bf16, 393 TOP/s int8, 16 GB of HBM at 819 GB/s per chip.
+"""
+
+PEAKS = {
+    "TPU v5 lite": {
+        "bf16_flop_per_s": 197e12,
+        "int8_op_per_s": 393e12,
+        "hbm_bytes_per_s": 819e9,
+        "hbm_bytes": 16e9,
+        "source": "Google Cloud documentation, TPU v5e",
+    },
+}
+
+
+def lookup(device_kind: str) -> dict:
+    """The peaks of ``device_kind``; ``KeyError`` for an unknown chip."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
