@@ -369,6 +369,8 @@ class DenseRPQ(dl.LiveUpdateEngine):
         self.results = result_cache if result_cache is not None else ResultCache()
         self.traces = TraceTracker()  # distinct BFS dispatch signatures
         self.hetero_dispatches = 0   # _bfs_hetero device calls
+        self.h2d_bytes = 0           # slot-tick planes and tables uploaded
+        self.d2h_bytes = 0           # slot-tick planes downloaded
         self.delta: Optional[dl.DeltaOverlay] = None  # live-update overlay
         self.compact_threshold = compact_threshold
         self.compactions = 0
@@ -1093,6 +1095,9 @@ class DenseStepper:
         self.eng = eng
         self.steps_per_tick = max(1, int(steps_per_tick))
         self.slots: List[_DenseSlot] = []
+        # per edge snapshot (keyed like step()'s groups, holding the
+        # arrays so the ids stay theirs): in-degree over live labels
+        self._indeg: Dict[Tuple, Tuple[Tuple, np.ndarray]] = {}
 
     # -- admission / retirement --------------------------------------------
     def add_job(self, plan: _DensePlan, start: int,
@@ -1123,6 +1128,28 @@ class DenseStepper:
         monotone, so callers stream the set difference per tick."""
         return {int(v) for v in np.nonzero(slot.visited[:, 0] > 0)[0]}
 
+    def useful(self, slot: _DenseSlot) -> int:
+        """Edge-states the supersteps had to read for ``slot`` so far:
+        the sum, over the (node, state) pairs set in its visited planes,
+        of the node's in-degree over live labels.  Each pair enters the
+        frontier once, and a frontier pair at node v reads the edges
+        whose object is v, whatever kernel implements the superstep.  A
+        pair found by the last superstep and not yet expanded counts
+        too, so for a slot retired on a hit this is an upper bound by
+        one frontier.  Costs a pass over the planes (and, once per edge
+        snapshot, a download of its label and object arrays)."""
+        key = tuple(id(a) for a in slot.edges)
+        if key not in self._indeg:
+            _subj, pred, obj = slot.edges
+            pred, obj = np.asarray(pred), np.asarray(obj)
+            if len(self._indeg) >= 4:      # at most two snapshots are live
+                self._indeg.clear()
+            self._indeg[key] = (slot.edges, np.bincount(
+                obj[pred < self.eng.dg.num_labels],
+                minlength=self.eng.graph.num_nodes))
+        indeg = self._indeg[key][1]
+        return int(np.count_nonzero(slot.visited, axis=1) @ indeg)
+
     # -- one tick -----------------------------------------------------------
     def step(self) -> bool:
         """Advance every active slot by up to ``steps_per_tick``
@@ -1142,32 +1169,47 @@ class DenseStepper:
                 C = 4
                 while C < len(members):
                     C *= 2
-                Bstk = np.zeros((C, L + 1, S_pad), dtype=np.int8)
-                PREDstk = np.zeros((C, S_pad, S_pad), dtype=np.int8)
-                front = np.zeros((C, V, S_pad), dtype=np.int8)
-                vis = np.zeros((C, V, S_pad), dtype=np.int8)
-                for r, slot in enumerate(members):
-                    S = slot.plan.g.m + 1
-                    B_host, PRED_host = slot.plan.host_tables()
-                    Bstk[r, :, :S] = B_host
-                    PREDstk[r, :S, :S] = PRED_host
-                    front[r] = slot.frontier
-                    vis[r] = slot.visited
+                with otrace.span("dense.restack", cat="engine", rows=C,
+                                 live=len(members), width=S_pad):
+                    Bstk = np.zeros((C, L + 1, S_pad), dtype=np.int8)
+                    PREDstk = np.zeros((C, S_pad, S_pad), dtype=np.int8)
+                    front = np.zeros((C, V, S_pad), dtype=np.int8)
+                    vis = np.zeros((C, V, S_pad), dtype=np.int8)
+                    for r, slot in enumerate(members):
+                        S = slot.plan.g.m + 1
+                        B_host, PRED_host = slot.plan.host_tables()
+                        Bstk[r, :, :S] = B_host
+                        PREDstk[r, :S, :S] = PRED_host
+                        front[r] = slot.frontier
+                        vis[r] = slot.visited
+                h2d = Bstk.nbytes + PREDstk.nbytes + front.nbytes + vis.nbytes
+                # f and v come back in the shapes and dtype they went up in
+                d2h = front.nbytes + vis.nbytes
+                with otrace.span("dense.upload", cat="transfer", bytes=h2d):
+                    planes = (jnp.asarray(Bstk), jnp.asarray(PREDstk),
+                              jnp.asarray(front), jnp.asarray(vis))
                 subj, pred, obj = members[0].edges
                 eng.traces.record("bfs_chunk_hetero", C, S_pad)
-                with otrace.span("dense.bfs_chunk", cat="kernel",
-                                 rows=C, width=S_pad, live=len(members)):
-                    f, v, it = _bfs_chunk_hetero(
-                        subj, pred, obj, jnp.asarray(Bstk),
-                        jnp.asarray(PREDstk), jnp.asarray(front),
-                        jnp.asarray(vis), V, self.steps_per_tick)
-                    eng.hetero_dispatches += 1
-                    eng._superstep_acc += int(it)
+                # the device wait is the block inside this span; the
+                # download below then copies finished buffers
+                with otrace.span("dense.bfs_chunk", cat="kernel", rows=C,
+                                 live=len(members), width=S_pad,
+                                 swept=C * int(subj.shape[0]) * S_pad
+                                 * self.steps_per_tick):
+                    out = _bfs_chunk_hetero(subj, pred, obj, *planes, V,
+                                            self.steps_per_tick)
+                    jax.block_until_ready(out)
+                eng.hetero_dispatches += 1
+                eng.h2d_bytes += h2d
+                eng.d2h_bytes += d2h
+                with otrace.span("dense.download", cat="transfer", bytes=d2h):
+                    f, v, it = out
                     f = np.asarray(f)
                     v = np.asarray(v)
-                for r, slot in enumerate(members):
-                    slot.frontier = f[r]
-                    slot.visited = v[r]
-                    if not f[r].any():
-                        slot.active = False
+                    eng._superstep_acc += int(it)
+                    for r, slot in enumerate(members):
+                        slot.frontier = f[r]
+                        slot.visited = v[r]
+                        if not f[r].any():
+                            slot.active = False
         return any(s.active for s in self.slots)

@@ -94,15 +94,18 @@ class QueryTicket:
     ``service_s`` (admission -> settle), ``supersteps_s`` (wall time
     the ticket's slot spent inside superstep dispatch).  For a settled
     ticket ``queue_wait_s + service_s == finished_at - submitted_at``.
+    ``rid`` is the ticket's submission sequence number, carried by its
+    admit, preempt and retire spans so a trace can join them.
     """
 
-    __slots__ = ("query", "submitted_at", "admitted_at", "deadline",
+    __slots__ = ("query", "rid", "submitted_at", "admitted_at", "deadline",
                  "epoch", "state", "finished_at", "stats", "_result",
                  "_error", "_stream", "_emitted")
 
     def __init__(self, query: Query, submitted_at: float,
-                 deadline: Optional[float]):
+                 deadline: Optional[float], rid: int = 0):
         self.query = query
+        self.rid = rid
         self.submitted_at = submitted_at
         self.admitted_at: Optional[float] = None
         self.deadline = deadline
@@ -193,6 +196,9 @@ class _RingSlots:
     def release(self, job) -> None:
         self.stepper.remove_job(job)
 
+    def useful(self, job) -> Optional[int]:
+        return None
+
 
 class _DenseSlots:
     """Dense-engine adapter: slots are independent hetero-bucket BFS
@@ -226,6 +232,9 @@ class _DenseSlots:
 
     def release(self, slot) -> None:
         self.stepper.remove_job(slot)
+
+    def useful(self, slot) -> Optional[int]:
+        return self.stepper.useful(slot)
 
 
 class SlotScheduler:
@@ -312,7 +321,8 @@ class SlotScheduler:
                 f"admission queue full ({self.max_queue} waiting)")
         now = self.clock()
         ticket = QueryTicket(as_query(query), now,
-                             now + deadline_s if deadline_s else None)
+                             now + deadline_s if deadline_s else None,
+                             rid=self.submitted)
         self.waiting.append(ticket)
         self.submitted += 1
         return ticket
@@ -346,16 +356,16 @@ class SlotScheduler:
             self._expire(now)
             self._admit(now)
             if self.active:
-                with otrace.span("scheduler.superstep", cat="scheduler",
-                                 slots=len(self.active)):
-                    t0 = self.clock()
-                    self.slots.step()
-                    dt = self.clock() - t0
+                t0 = self.clock()
+                self.slots.step()
+                dt = self.clock() - t0
                 # wall time inside superstep dispatch, attributed to every
                 # ticket that occupied a slot during it
                 for a in self.active:
                     a.ticket.stats.supersteps_s += dt
-                self._harvest()
+                with otrace.span("scheduler.harvest", cat="scheduler",
+                                 slots=len(self.active)):
+                    self._harvest()
         return bool(self.active or self.waiting)
 
     def drain(self) -> None:
@@ -384,6 +394,14 @@ class SlotScheduler:
         m.gauge("rpq_waiting", "admission queue depth").set(len(self.waiting))
         m.gauge("rpq_peak_in_flight",
                 "high-water occupied slots").set(self.peak_in_flight)
+        if hasattr(self.engine, "h2d_bytes"):
+            # the dense slot tick's plane traffic, both directions
+            m.counter("rpq_dense_h2d_bytes_total",
+                      "slot-tick bytes uploaded to the device"
+                      ).value = self.engine.h2d_bytes
+            m.counter("rpq_dense_d2h_bytes_total",
+                      "slot-tick bytes downloaded from the device"
+                      ).value = self.engine.d2h_bytes
         # self-observability: the obs layer reports its own saturation
         m.counter("rpq_tracer_dropped_events_total",
                   "span events dropped at the tracer's max_events bound"
@@ -455,9 +473,12 @@ class SlotScheduler:
         self._hist_e2e.observe(ticket.finished_at - ticket.submitted_at)
 
     def _finish(self, ticket: QueryTicket, out: Set[Tuple[int, int]],
-                key: Tuple, footprint: frozenset) -> None:
-        with otrace.span("scheduler.retire", cat="scheduler",
-                         expr=ticket.query.expr, results=len(out)):
+                key: Tuple, footprint: frozenset,
+                useful: Optional[int] = None) -> None:
+        counted = {} if useful is None else {"useful": useful}
+        with otrace.span("scheduler.retire", cat="scheduler", rid=ticket.rid,
+                         expr=ticket.query.expr, results=len(out),
+                         **counted):
             q = ticket.query
             ticket.stats.results = len(out)
             out = truncate_result(out, q.limit)
@@ -477,7 +498,8 @@ class SlotScheduler:
                        if t.deadline is not None and now >= t.deadline]:
             self.waiting.remove(ticket)
             with otrace.span("scheduler.preempt", cat="scheduler",
-                             where="queued", expr=ticket.query.expr):
+                             rid=ticket.rid, where="queued",
+                             expr=ticket.query.expr):
                 ticket.stats.queue_wait_s = now - ticket.submitted_at
                 self._hist_preempt_wait.observe(ticket.stats.queue_wait_s)
                 self._fail(ticket, TimeoutError("query deadline exceeded"))
@@ -488,7 +510,8 @@ class SlotScheduler:
             # deadline-aware preemption: the slot frees THIS tick, so
             # the stragglers behind it stop paying for the monster query
             with otrace.span("scheduler.preempt", cat="scheduler",
-                             where="running", expr=a.ticket.query.expr):
+                             rid=a.ticket.rid, where="running",
+                             expr=a.ticket.query.expr):
                 self.slots.release(a.handle)
                 self.active.remove(a)
                 self._hist_preempt_wait.observe(a.ticket.stats.queue_wait_s)
@@ -520,6 +543,8 @@ class SlotScheduler:
             ticket.stats.queue_wait_s = now - ticket.submitted_at
             self._hist_queue_wait.observe(ticket.stats.queue_wait_s)
             with otrace.span("scheduler.admit", cat="scheduler",
+                             rid=ticket.rid,
+                             queue_wait_ms=ticket.stats.queue_wait_s * 1e3,
                              expr=ticket.query.expr) as sp:
                 try:
                     self._admit_one(ticket, now)
@@ -640,6 +665,10 @@ class SlotScheduler:
             hit = a.kind == "both" and a.target in a.seen
             if not hit and not self.slots.finished(a.handle):
                 continue
+            # the sweep's useful work is counted only while tracing: it
+            # is a pass over the slot's planes the untraced tick skips
+            useful = self.slots.useful(a.handle) \
+                if otrace.TRACER.enabled else None
             self.slots.release(a.handle)
             self.active.remove(a)
             null = rx.nullable(rx.parse(q.expr))
@@ -655,7 +684,7 @@ class SlotScheduler:
                 if null:
                     out.add((q.subject, q.subject))
                 out.update((q.subject, o) for o in a.seen)
-            self._finish(ticket, out, a.key, a.footprint)
+            self._finish(ticket, out, a.key, a.footprint, useful=useful)
 
 
 _DONE = object()
@@ -812,13 +841,17 @@ class AsyncServer:
         return self.scheduler.submit_update(add=add, remove=remove)
 
     def _flush(self) -> None:
-        for at in list(self._live):
-            for pair in at.ticket.new_pairs():
-                self._queue_put(at, pair)
-            if at.ticket.done:
-                self._queue_put(at, _DONE)
-                at._settled.set()
-                self._live.remove(at)
+        if not self._live:
+            return
+        with otrace.span("server.flush", cat="server",
+                         tickets=len(self._live)):
+            for at in list(self._live):
+                for pair in at.ticket.new_pairs():
+                    self._queue_put(at, pair)
+                if at.ticket.done:
+                    self._queue_put(at, _DONE)
+                    at._settled.set()
+                    self._live.remove(at)
 
     @staticmethod
     def _queue_put(at: AsyncTicket, item) -> None:

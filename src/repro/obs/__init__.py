@@ -40,12 +40,12 @@ from .explain import ExplainSink, analyze_query, explain_query, validate_report
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       diff_snapshots)
 from .recorder import FlightRecorder
-from .trace import NULL_SPAN, Tracer, bypass, instant, span, use
+from .trace import NULL_SPAN, Tracer, bypass, span, use
 
 __all__ = [
     "explain", "metrics", "recorder", "trace",
     "ExplainSink", "analyze_query", "explain_query", "validate_report",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "diff_snapshots",
     "FlightRecorder",
-    "NULL_SPAN", "Tracer", "bypass", "instant", "span", "use",
+    "NULL_SPAN", "Tracer", "bypass", "span", "use",
 ]

@@ -18,15 +18,22 @@ Design constraints, in order:
    ``jax.profiler`` is importable, every span also enters a
    ``TraceAnnotation`` so host spans line up with device activity in a
    jax profiler capture; absent jax the tracer works identically (the
-   standing optional-dep shim pattern).
+   standing optional-dep shim pattern).  The annotation carries the
+   span's numeric arguments (``int``/``float``/``bool``, given when the
+   span is *opened*) as event stats under the span's bare name; string
+   and other arguments, and anything passed to :meth:`Span.set` after
+   entry, stay in the tracer's own Chrome-trace events only (the
+   profiler encodes arguments as ``name#k=v,...#``, which a regex in a
+   string argument could break).
 
 The module-level :data:`TRACER` is what the instrumented call sites in
-``repro.core`` use (via :func:`span` / :func:`instant`, which read the
-global at call time so :func:`use` / :func:`bypass` can swap it).
+``repro.core`` use (via :func:`span`, which reads the global at call
+time so :func:`use` / :func:`bypass` can swap it).
 """
 from __future__ import annotations
 
 import json
+import numbers
 import threading
 import time
 from contextlib import contextmanager
@@ -37,8 +44,8 @@ try:  # optional-dep shim: the bridge is a bonus, never load-bearing
 except ImportError:  # pragma: no cover - exercised by the minimal CI leg
     _JaxTraceAnnotation = None
 
-__all__ = ["NULL_SPAN", "Span", "Tracer", "TRACER", "span", "instant",
-           "use", "bypass"]
+__all__ = ["NULL_SPAN", "Span", "Tracer", "TRACER", "span", "use",
+           "bypass"]
 
 
 class _NullSpan:
@@ -65,7 +72,8 @@ NULL_SPAN = _NullSpan()
 class Span:
     """One live span: a context manager that records a Chrome complete
     event on exit.  ``set(**args)`` attaches arguments any time before
-    exit (shown in the Perfetto args panel)."""
+    exit (shown in the Perfetto args panel); they cannot reach the jax
+    profiler's annotation, which took its arguments at entry."""
 
     __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_jax")
 
@@ -85,7 +93,9 @@ class Span:
     def __enter__(self) -> "Span":
         self._t0 = self._tracer._clock()
         if self._tracer.jax_annotations and _JaxTraceAnnotation is not None:
-            self._jax = _JaxTraceAnnotation(self.name)
+            self._jax = _JaxTraceAnnotation(
+                self.name, **{k: v for k, v in self.args.items()
+                              if isinstance(v, numbers.Real)})
             self._jax.__enter__()
         return self
 
@@ -140,16 +150,6 @@ class Tracer:
             return NULL_SPAN
         return Span(self, name, cat, args)
 
-    def instant(self, name: str, cat: str = "serving", **args) -> None:
-        """A zero-duration marker event (``ph: "i"``)."""
-        if not self.enabled:
-            return
-        now = self._clock()
-        self._append({"name": name, "cat": cat, "ph": "i", "s": "t",
-                      "ts": self._us(now), "pid": 1,
-                      "tid": threading.get_ident() % 0x7FFFFFFF,
-                      "args": dict(args)})
-
     def _record(self, name: str, cat: str, t0: float, t1: float,
                 args: Dict[str, Any]) -> None:
         self._append({"name": name, "cat": cat, "ph": "X",
@@ -186,16 +186,13 @@ class Tracer:
 
 
 class _BypassTracer(Tracer):
-    """Hard-null tracer: span()/instant() short-circuit before even the
+    """Hard-null tracer: span() short-circuits before even the
     ``enabled`` check — the closest runtime stand-in for removing the
     instrumentation, used by ``benchmarks/serving.py`` to price the
     disabled call sites (the ``tracer_off_overhead`` row)."""
 
     def span(self, name: str, cat: str = "serving", **args) -> Any:
         return NULL_SPAN
-
-    def instant(self, name: str, cat: str = "serving", **args) -> None:
-        return None
 
 
 TRACER = Tracer()
@@ -211,10 +208,6 @@ def span(name: str, cat: str = "serving", **args) -> Any:
     if not t.enabled:
         return NULL_SPAN
     return t.span(name, cat, **args)
-
-
-def instant(name: str, cat: str = "serving", **args) -> None:
-    TRACER.instant(name, cat, **args)
 
 
 @contextmanager

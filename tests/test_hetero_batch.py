@@ -190,3 +190,96 @@ def test_eval_many_stats_surface_result_cache():
     assert [s.result_cache_hits for s in stats_out] == [1, 1]
     assert replay == res
     assert [s.results for s in stats_out] == [len(r) for r in res]
+
+
+# ---------------------------------------------------------------------
+# the dense slot tick under the tracer: transfer bytes and sweep work
+# ---------------------------------------------------------------------
+
+def _traced_dense_drain(g, queries, max_slots):
+    """Drain ``queries`` through a traced dense ``SlotScheduler``; also
+    returns the slot handles in the order they were admitted."""
+    from repro.core.scheduler import SlotScheduler
+    from repro.obs import trace as otrace
+    tr = otrace.Tracer()
+    tr.enable()
+    handles = []
+    with otrace.use(tr):
+        sched = SlotScheduler(make_engine(g, "dense"), max_slots=max_slots)
+        admit = sched.slots.admit
+
+        def keep(*args, **kw):
+            handles.append(admit(*args, **kw))
+            return handles[-1]
+
+        sched.slots.admit = keep
+        tickets = [sched.submit(q) for q in queries]
+        sched.drain()
+    return sched, tickets, tr.events, handles
+
+
+def test_dense_tick_transfer_bytes_are_the_planes_nbytes():
+    g = random_graph(20, 3, 80, seed=31, pred_zipf=False)
+    queries = [Query("0", obj=o) for o in range(3)] + \
+              [Query("0/1/2/0/1/2/0/1", obj=o) for o in range(2)]
+    sched, _, evs, _ = _traced_dense_drain(g, queries, max_slots=5)
+    eng = sched.engine
+    V, L = g.num_nodes, eng.dg.num_labels
+    ups = [e for e in evs if e["name"] == "dense.upload"]
+    downs = [e for e in evs if e["name"] == "dense.download"]
+    chunks = [e for e in evs if e["name"] == "dense.bfs_chunk"]
+    restacks = [e for e in evs if e["name"] == "dense.restack"]
+    assert len(ups) == len(downs) == len(chunks) == len(restacks) > 0
+    E = int(eng.dg.subj.shape[0])
+    for r, u, d, c in zip(restacks, ups, downs, chunks):
+        C, S = r["args"]["rows"], r["args"]["width"]
+        assert c["args"]["rows"] == C and c["args"]["width"] == S
+        assert 1 <= r["args"]["live"] <= C
+        # int8 planes: B [C, L+1, S], PRED [C, S, S], frontier and
+        # visited [C, V, S] up; frontier and visited down
+        assert u["args"]["bytes"] == C * (L + 1) * S + C * S * S \
+            + 2 * C * V * S
+        assert d["args"]["bytes"] == 2 * C * V * S
+        assert c["args"]["swept"] == C * E * S
+    assert {r["args"]["width"] for r in restacks} == {4, 16}
+    assert eng.h2d_bytes == sum(u["args"]["bytes"] for u in ups)
+    assert eng.d2h_bytes == sum(d["args"]["bytes"] for d in downs)
+    snap = sched.metrics_snapshot()
+    assert snap["rpq_dense_h2d_bytes_total"] == eng.h2d_bytes
+    assert snap["rpq_dense_d2h_bytes_total"] == eng.d2h_bytes
+    assert "rpq_dense_h2d_bytes_total" in sched.prometheus_text()
+
+
+def test_useful_sweep_work_is_the_visited_in_degree():
+    """``useful`` on a dense retirement is the sum, over the slot's
+    visited (node, state) pairs, of the node's in-degree in the
+    completed graph — and never more than the edge-states swept."""
+    import numpy as np
+    g = random_graph(25, 3, 110, seed=24, pred_zipf=False)
+    queries = [Query("0/1*", obj=3), Query("(0|1)/(2|0)+", subject=2),
+               Query("2*/0", obj=7), Query("^1/0*", subject=4)]
+    sched, tickets, evs, handles = _traced_dense_drain(g, queries,
+                                                       max_slots=2)
+    assert len(handles) == len(queries)          # FIFO: one slot each
+    _s, _p, o = g.completed_triples()
+    indeg = np.bincount(o, minlength=g.num_nodes)
+    retires = {e["args"]["rid"]: e["args"] for e in evs
+               if e["name"] == "scheduler.retire"}
+    for i, t in enumerate(tickets):
+        vis = handles[i].visited
+        want = sum(int(indeg[v]) for v, q in zip(*np.nonzero(vis)))
+        assert retires[t.rid]["useful"] == want > 0, (i, t.query)
+        # planes in another memory layout (as a device may hand them
+        # back) count the same
+        handles[i].visited = np.asfortranarray(vis)
+        assert not handles[i].visited.flags.c_contiguous
+        assert sched.slots.useful(handles[i]) == want
+    swept = sum(e["args"]["swept"] for e in evs
+                if e["name"] == "dense.bfs_chunk")
+    assert 0 < sum(a["useful"] for a in retires.values()) <= swept
+    # untraced, nothing is counted
+    from repro.core.scheduler import SlotScheduler
+    plain = SlotScheduler(make_engine(g, "dense"), max_slots=2)
+    plain.submit(queries[0])
+    plain.drain()
+    assert plain.slots.stepper._indeg == {}

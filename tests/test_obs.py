@@ -3,6 +3,7 @@
 Histogram quantiles vs exact sample percentiles (the bounded-relative-
 error property), span nesting + Chrome trace-event schema validity, the
 disabled-tracer no-op property (NULL_SPAN identity, zero events), the
+jax profiler bridge (numeric span arguments only), the
 metrics registry (get-or-create, kind mismatch, snapshot/diff), and
 Prometheus text-exposition parseability."""
 import json
@@ -86,7 +87,8 @@ def test_disabled_tracer_is_a_shared_noop():
     assert tr.span("x") is ot.NULL_SPAN       # no allocation per call
     with tr.span("x") as sp:
         sp.set(a=1)
-    tr.instant("y")
+    with tr.span("y", rows=8):
+        pass
     assert tr.events == [] and tr.dropped == 0
     # module-level path: off by default in a fresh tracer swap
     with ot.use(ot.Tracer()):
@@ -115,21 +117,26 @@ def test_span_nesting_and_chrome_trace_schema(tmp_path):
         with ot.span("outer", cat="test", depth=0):
             with ot.span("inner", cat="test") as sp:
                 sp.set(depth=1)
-            ot.instant("marker", note="hi")
+            with ot.span("sibling", cat="test", note="hi"):
+                pass
     doc = tr.chrome_trace()
     json.dumps(doc)                           # must be JSON-able
     evs = doc["traceEvents"]
-    assert [e["name"] for e in evs] == ["inner", "marker", "outer"]
+    assert [e["name"] for e in evs] == ["inner", "sibling", "outer"]
     by_name = {e["name"]: e for e in evs}
     for e in evs:
         assert set(e) >= {"name", "cat", "ph", "ts", "pid", "tid", "args"}
         assert e["ts"] >= 0
-    assert by_name["outer"]["ph"] == "X" and by_name["marker"]["ph"] == "i"
-    # time containment (what viewers nest by): inner inside outer
-    outer, inner = by_name["outer"], by_name["inner"]
-    assert outer["ts"] <= inner["ts"]
-    assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
+        assert e["ph"] == "X"
+    # time containment (what viewers nest by): both children inside
+    # outer, one after the other
+    outer, inner, sib = by_name["outer"], by_name["inner"], by_name["sibling"]
+    for child in (inner, sib):
+        assert outer["ts"] <= child["ts"]
+        assert child["ts"] + child["dur"] <= outer["ts"] + outer["dur"]
+    assert inner["ts"] + inner["dur"] <= sib["ts"]
     assert inner["args"] == {"depth": 1}
+    assert sib["args"] == {"note": "hi"}
     # export round-trip
     path = tr.export(str(tmp_path / "trace.json"))
     with open(path) as f:
@@ -140,11 +147,41 @@ def test_tracer_drops_beyond_max_events():
     tr = ot.Tracer(max_events=3)
     tr.enable()
     for i in range(5):
-        tr.instant(f"e{i}")
-    assert len(tr.events) == 3 and tr.dropped == 2
+        with tr.span(f"e{i}"):
+            pass
+    assert [e["name"] for e in tr.events] == ["e0", "e1", "e2"]
+    assert tr.dropped == 2
     assert tr.chrome_trace()["otherData"]["dropped_events"] == 2
     tr.clear()
     assert tr.events == [] and tr.dropped == 0
+
+
+def test_bridge_carries_numeric_span_arguments_to_the_profiler(tmp_path):
+    """With the jax bridge on, a span lands in the profiler's ``.xplane.pb``
+    under its bare name, with its numeric arguments as event stats; a
+    string argument (here one holding the profiler's own separators)
+    stays in the tracer's Chrome events only."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    tr = ot.Tracer()
+    tr.enable(jax_annotations=True)
+    assert tr.jax_annotations
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with ot.use(tr):
+            with ot.span("test.bridge", rows=8, bytes=123, expr="a,b=c#"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    found = [ev for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events
+             if "test.bridge" in ev.name]
+    assert [ev.name for ev in found] == ["test.bridge"]
+    stats = dict(found[0].stats)
+    assert stats == {"rows": 8, "bytes": 123}
+    assert tr.events[0]["args"] == {"rows": 8, "bytes": 123, "expr": "a,b=c#"}
 
 
 # ---------------------------------------------------------------------

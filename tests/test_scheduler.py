@@ -334,7 +334,7 @@ def test_zero_slack_deadline_preempts_deterministically():
 
 
 def test_spans_cover_scheduler_and_both_engines():
-    """A traced drain produces admission, superstep, and retire spans —
+    """A traced drain produces admission, harvest, and retire spans —
     plus the engine's own superstep span — for ring AND dense, and the
     result is a valid Chrome trace document."""
     import json
@@ -350,8 +350,9 @@ def test_spans_cover_scheduler_and_both_engines():
             sched.submit(Query("(0|1)/2", subject=2))
             sched.drain()
         names = {e["name"] for e in tr.events}
-        assert {"scheduler.tick", "scheduler.admit", "scheduler.superstep",
+        assert {"scheduler.tick", "scheduler.admit", "scheduler.harvest",
                 "scheduler.retire", eng_span} <= names, (kind, names)
+        assert "scheduler.superstep" not in names
         json.dumps(tr.chrome_trace())         # schema is JSON-able
     # and with the (default-off) module tracer, the same drain records
     # nothing and allocates no spans
@@ -361,6 +362,115 @@ def test_spans_cover_scheduler_and_both_engines():
     sched.submit(Query("0/1*", obj=3))
     sched.drain()
     assert TRACER.events == []
+
+
+def _inside(inner, outer):
+    return outer["ts"] <= inner["ts"] and \
+        inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
+
+
+def _parent(ev, events, name):
+    """The ``name`` span that contains ``ev`` (nested by time)."""
+    found = [e for e in events if e["name"] == name and _inside(ev, e)]
+    assert len(found) == 1, (ev["name"], name, len(found))
+    return found[0]
+
+
+def test_dense_tick_spans_nest_inside_the_tick():
+    """A traced dense drain breaks each tick into restack, upload, the
+    device wait and download, each inside ``dense.superstep`` inside
+    ``scheduler.tick``; the harvest holds every slot retirement, and
+    the retired superstep span is gone."""
+    from repro.obs import trace as otrace
+    g = random_graph(12, 3, 40, seed=6, pred_zipf=False)
+    tr = otrace.Tracer()
+    tr.enable()
+    with otrace.use(tr):
+        sched = SlotScheduler(make_engine(g, "dense"), max_slots=3)
+        for q in (Query("0/1*", obj=3), Query("(0|1)/2", subject=2),
+                  Query("2+", subject=1, obj=4), Query("^1/0*", obj=5)):
+            sched.submit(q)
+        sched.drain()
+    evs = tr.events
+    names = [e["name"] for e in evs]
+    assert "scheduler.superstep" not in names
+    phases = ("dense.restack", "dense.upload", "dense.bfs_chunk",
+              "dense.download")
+    counts = {n: names.count(n) for n in phases}
+    assert counts["dense.bfs_chunk"] > 0
+    assert len(set(counts.values())) == 1, counts    # one each per dispatch
+    for e in evs:
+        if e["name"] in phases:
+            sup = _parent(e, evs, "dense.superstep")
+            _parent(sup, evs, "scheduler.tick")
+        if e["name"] == "scheduler.harvest":
+            _parent(e, evs, "scheduler.tick")
+            assert e["args"]["slots"] >= 1
+    # the four phases of one dispatch run in order
+    for chunk in (e for e in evs if e["name"] == "dense.bfs_chunk"):
+        sup = _parent(chunk, evs, "dense.superstep")
+        mine = sorted((e for e in evs if e["name"] in phases
+                       and _inside(e, sup)), key=lambda e: e["ts"])
+        assert [e["name"] for e in mine] == list(phases)
+    retires = [e for e in evs if e["name"] == "scheduler.retire"]
+    assert len(retires) == 4
+    in_harvest = [e for e in retires
+                  if any(h["name"] == "scheduler.harvest" and _inside(e, h)
+                         for h in evs)]
+    assert in_harvest and all("useful" in e["args"] for e in in_harvest)
+
+
+def test_admit_and_retire_spans_share_a_request_id():
+    from repro.obs import trace as otrace
+    g = random_graph(12, 3, 40, seed=6, pred_zipf=False)
+    now = [0.0]
+    tr = otrace.Tracer()
+    tr.enable()
+    with otrace.use(tr):
+        sched = SlotScheduler(make_engine(g, "ring"), max_slots=1,
+                              clock=lambda: now[0])
+        tickets = [sched.submit(Query(e, obj=3)) for e in ("0/1*", "2+",
+                                                           "(0|1)/2")]
+        now[0] = 0.25
+        sched.drain()
+    assert [t.rid for t in tickets] == [0, 1, 2]
+    admits = {e["args"]["rid"]: e["args"] for e in tr.events
+              if e["name"] == "scheduler.admit"}
+    retires = {e["args"]["rid"]: e["args"] for e in tr.events
+               if e["name"] == "scheduler.retire"}
+    assert sorted(admits) == sorted(retires) == [0, 1, 2]
+    for t in tickets:
+        assert admits[t.rid]["expr"] == retires[t.rid]["expr"] == t.query.expr
+        assert admits[t.rid]["queue_wait_ms"] == pytest.approx(
+            t.stats.queue_wait_s * 1e3)
+    # the first waited from submission to the first tick; the others
+    # for the one slot as well
+    assert admits[0]["queue_wait_ms"] == pytest.approx(250.0)
+    assert "useful" not in retires[0]            # the ring counts no sweep
+
+
+def test_async_server_flush_is_a_span():
+    from repro.obs import trace as otrace
+    g = random_graph(12, 3, 40, seed=6, pred_zipf=False)
+    eng = make_engine(g, "dense")
+    tr = otrace.Tracer()
+    tr.enable()
+
+    async def main():
+        async with AsyncServer(SlotScheduler(eng, max_slots=2)) as server:
+            t1 = await server.submit(Query("0/1*", obj=3))
+            t2 = await server.submit(Query("(0|1)/2", subject=2))
+            return await t1.result(), await t2.result()
+
+    with otrace.use(tr):
+        r1, r2 = asyncio.run(main())
+    assert r1 == eval_oracle(g, "0/1*", None, 3)
+    assert r2 == eval_oracle(g, "(0|1)/2", 2, None)
+    flushes = [e for e in tr.events if e["name"] == "server.flush"]
+    assert flushes and all(1 <= e["args"]["tickets"] <= 2 for e in flushes)
+    # a flush follows its tick, never inside it
+    ticks = [e for e in tr.events if e["name"] == "scheduler.tick"]
+    assert not any(_inside(f, t) for f in flushes for t in ticks)
 
 
 def test_async_server_metrics_endpoint_scrapes():
