@@ -160,17 +160,19 @@ def dense_phase(nodes: int, preds: int, edges: int, num_queries: int,
     # with empty frontiers, so the timed pass compiles nothing
     t0 = time.perf_counter()
     V, L = g.num_nodes, eng.dg.num_labels
-    subj, pred, obj = eng._edges()
+    e = eng._edges()
+    sorted_kw = {"off": e.off, "n_sorted": e.n_sorted}
     shapes = [(C, S) for C in _row_buckets(max_slots) for S in widths]
     for C, S in shapes:
-        args = (subj, pred, obj, jnp.zeros((C, L + 1, S), jnp.int8),
+        args = (e.subj, e.pred, e.obj, jnp.zeros((C, L + 1, S), jnp.int8),
                 jnp.zeros((C, S, S), jnp.int8),
                 jnp.zeros((C, V, S), jnp.int8),
                 jnp.zeros((C, V, S), jnp.int8))
-        jax.block_until_ready(dense._bfs_chunk_hetero(*args, V, 1))
+        jax.block_until_ready(dense._bfs_chunk_hetero(*args, V, 1,
+                                                      **sorted_kw))
         eng.traces.record("bfs_chunk_hetero", C, S)
-    mem = dense._bfs_chunk_hetero.lower(*args, V, 1).compile() \
-        .memory_analysis()
+    mem = dense._bfs_chunk_hetero.lower(*args, V, 1, **sorted_kw) \
+        .compile().memory_analysis()
     log(f"dense: warmed {len(shapes)} slot-tick shapes (rows x width) "
         f"{shapes} in {time.perf_counter() - t0:.1f} s")
     log(f"dense: chunk program rows={C} width={S} compile-time "

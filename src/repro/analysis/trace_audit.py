@@ -200,17 +200,20 @@ def check_kernel_contracts() -> List[Finding]:
 
 
 def check_hetero_bfs() -> List[Finding]:
-    """The hetero-bucket vmapped BFS: int32 edge ids, int8 planes in and
-    out, no host round-trips across the whole unrolled superstep chain."""
+    """The hetero-bucket vmapped BFS over subject-sorted edges with their
+    segment offsets: int32 edge ids, int8 planes in and out, no host
+    round-trips across the whole unrolled superstep chain."""
     from ..core import dense
 
     i8, i32 = jnp.int8, jnp.int32
     R, V, S, L, E = 3, 16, 8, 4, 40
     return audit_jaxpr(
-        lambda *a: dense._bfs_hetero(*a, num_nodes=V, max_steps=V * S + 1),
+        lambda *a: dense._bfs_hetero(*a[:6], num_nodes=V,
+                                     max_steps=V * S + 1, off=a[6],
+                                     n_sorted=E),
         (_sds((E,), i32), _sds((E,), i32), _sds((E,), i32),
          _sds((R, L + 1, S), i8), _sds((R, S, S), i8),
-         _sds((R, V, S), i8)),
+         _sds((R, V, S), i8), _sds((V + 1,), i32)),
         label="dense._bfs_hetero", file="src/repro/core/dense.py",
         expect_out_dtypes=[i8])
 

@@ -16,7 +16,11 @@ One BFS superstep over the *backward* product graph is
     visited   |= new ; frontier = new
 
 where PRED[j,i] = 1 iff state i reaches state j in one NFA step.  With
-boolean planes this is literally an int8 matmul + segment-max — MXU food.
+boolean planes this is an int8 matmul and a sorted-segment OR: the edges
+are sorted by subject, so the OR over a node's run of rows is a running
+count along the edge axis read at the node's segment offsets — no
+scatter (rows appended after the sorted prefix, an insert buffer, are
+still scattered).
 A node is an *answer* when its state-0 (initial) plane lights up, exactly
 as the ring engine reports subjects (Sec. 4.2).
 
@@ -60,7 +64,7 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence, Set, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Set, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -79,14 +83,34 @@ from .ring import LabeledGraph
 from .stats import GraphStats
 
 
+class EdgeSet(NamedTuple):
+    """The edge rows one BFS sweeps: the first ``n_sorted`` rows are
+    sorted by ``subj``, with ``off`` [V + 1] their segment offsets
+    (``off[v]`` = first row whose subject is >= v), so the superstep
+    ORs them with no scatter; rows after them (a live overlay's insert
+    buffer) are scattered."""
+
+    subj: jnp.ndarray  # [E] int32
+    pred: jnp.ndarray  # [E] int32
+    obj: jnp.ndarray   # [E] int32
+    off: jnp.ndarray   # [V + 1] int32
+    n_sorted: int
+
+    @property
+    def tail_rows(self) -> int:
+        return int(self.subj.shape[0]) - self.n_sorted
+
+
 @dataclass
 class DenseGraph:
     """Device-resident completed graph, edges sorted by backward-push
-    destination (= subject) for the segment-OR."""
+    destination (= subject) for the sorted-segment OR, with their
+    segment offsets."""
 
     subj: jnp.ndarray  # [E] int32, sorted ascending
     pred: jnp.ndarray  # [E] int32 in [0, 2P)
     obj: jnp.ndarray   # [E] int32
+    off: jnp.ndarray   # [V + 1] int32: off[v] = first row with subj >= v
     num_nodes: int
     num_labels: int    # 2P
 
@@ -95,13 +119,23 @@ class DenseGraph:
         P = g.num_preds
         s, p, o = g.completed_triples()
         order = np.argsort(s, kind="stable")
+        s = s[order]
         return cls(
-            subj=jnp.asarray(s[order], dtype=jnp.int32),
+            subj=jnp.asarray(s, dtype=jnp.int32),
             pred=jnp.asarray(p[order], dtype=jnp.int32),
             obj=jnp.asarray(o[order], dtype=jnp.int32),
+            off=jnp.asarray(np.searchsorted(s, np.arange(g.num_nodes + 1)),
+                            dtype=jnp.int32),
             num_nodes=g.num_nodes,
             num_labels=2 * P,
         )
+
+    @functools.cached_property
+    def edges(self) -> EdgeSet:
+        """The base edges as one snapshot object (stable identity, so
+        slots pinned to it group together), every row sorted."""
+        return EdgeSet(self.subj, self.pred, self.obj, self.off,
+                       int(self.subj.shape[0]))
 
 
 def _start_row(g: Glushkov) -> np.ndarray:
@@ -133,74 +167,101 @@ def _plane_tables(g: Glushkov, num_labels: int):
     return jnp.asarray(B), jnp.asarray(PRED), jnp.asarray(F)
 
 
-def _edge_scatter(subj, pred, obj, B, PRED, frontier, num_segments):
+def _sorted_segment_or(Y, off):
+    """OR of ``Y``'s rows over each run ``[off[v], off[v + 1])`` of
+    subject-sorted edge rows, with no scatter: an inclusive running
+    count along the edge axis (int32: a hub's count overflows narrower
+    ints), a zero row in front, one gather at the V + 1 offsets, and
+    adjacent differences.  Work linear in E.  Returns bool [V, S]."""
+    run = jnp.cumsum(Y.astype(jnp.int32), axis=0)
+    run = jnp.concatenate([jnp.zeros((1,) + Y.shape[1:], jnp.int32), run])
+    at = run[off]
+    return at[1:] > at[:-1]
+
+
+def _edge_scatter(subj, pred, obj, B, PRED, frontier, num_segments,
+                  off=None, n_sorted=0):
     """The shared half of a superstep: Fact-1 edge mask -> bit-matrix
-    step -> segment-OR.  Also the sharded supersteps' local body
-    (``repro.core.distributed``), where ``frontier`` is the gathered
-    full array while the scatter targets only the shard's own rows —
-    keeping the math in ONE place is what guarantees sharded results
-    stay bit-identical to single-device runs."""
+    step -> sorted-segment OR.  Rows ``[0, n_sorted)`` are sorted by
+    subject with segment offsets ``off`` and are ORed without a
+    scatter; the rows after them (an unsorted insert buffer), or every
+    row when ``off`` is None, go through ``segment_max``.  Also the
+    sharded supersteps' local body (``repro.core.distributed``), which
+    passes no offsets, where ``frontier`` is the gathered full array
+    while the scatter targets only the shard's own rows — keeping the
+    math in ONE place is what guarantees sharded results stay
+    bit-identical to single-device runs.  Returns int8 0/1 planes."""
     X = frontier[obj] * B[pred]                       # [E, S]
     Y = (X.astype(jnp.int32) @ PRED.astype(jnp.int32)) > 0
-    scat = jax.ops.segment_max(
-        Y.astype(jnp.int8), subj, num_segments=num_segments
-    )
-    return jnp.maximum(scat, 0)
+    n = n_sorted if off is not None else 0
+    if n:
+        hit = _sorted_segment_or(Y[:n], off)
+    else:
+        hit = jnp.zeros((num_segments, Y.shape[-1]), dtype=bool)
+    if n < Y.shape[0]:
+        hit = hit | (jax.ops.segment_max(
+            Y[n:].astype(jnp.int8), subj[n:], num_segments=num_segments) > 0)
+    return hit.astype(jnp.int8)
 
 
-def _step_core(subj, pred, obj, B, PRED, frontier, visited, num_nodes):
+def _step_core(subj, pred, obj, B, PRED, frontier, visited, num_nodes,
+               off=None, n_sorted=0):
     """One backward product-graph superstep (the docstring's four lines):
     edge scatter, then merge into the monotone visited planes."""
-    scat = _edge_scatter(subj, pred, obj, B, PRED, frontier, num_nodes)
+    scat = _edge_scatter(subj, pred, obj, B, PRED, frontier, num_nodes,
+                         off, n_sorted)
     new = jnp.logical_and(scat > 0, visited == 0).astype(jnp.int8)
     return new, visited | new
 
 
-@functools.partial(jax.jit, static_argnames=("num_nodes", "max_steps"))
-def _bfs(
-    subj, pred, obj, B, PRED, start_planes, num_nodes: int, max_steps: int
-):
-    """Single-frontier BFS.  start_planes: [V, S] int8.  Returns visited
-    [V, S] (int8) after convergence (or max_steps)."""
+# Every BFS entry point takes the edge rows (subj, pred, obj), and as
+# keywords their segment offsets ``off`` and the static length
+# ``n_sorted`` of their sorted prefix (see :class:`EdgeSet`); without
+# ``off`` every row is scattered.
 
+
+def _bfs_loop(subj, pred, obj, B, PRED, frontier, visited, num_nodes,
+              max_steps, off, n_sorted):
+    """Supersteps until the frontier empties or ``max_steps`` trips.
+    Returns (frontier, visited, trips)."""
     def step(state):
-        frontier, visited, it = state
-        new, vis = _step_core(subj, pred, obj, B, PRED, frontier, visited,
-                              num_nodes)
+        f, v, it = state
+        new, vis = _step_core(subj, pred, obj, B, PRED, f, v, num_nodes,
+                              off, n_sorted)
         return new, vis, it + 1
 
     def cond(state):
-        frontier, _, it = state
-        return jnp.logical_and(jnp.any(frontier > 0), it < max_steps)
+        f, _, it = state
+        return jnp.logical_and(jnp.any(f > 0), it < max_steps)
 
-    frontier0 = start_planes
-    visited0 = start_planes
-    out = jax.lax.while_loop(cond, step, (frontier0, visited0, jnp.int32(0)))
-    return out[1], out[2]
+    return jax.lax.while_loop(cond, step,
+                              (frontier, visited, jnp.int32(0)))
 
 
-@functools.partial(jax.jit, static_argnames=("num_nodes", "max_steps"))
-def _bfs_batched(subj, pred, obj, B, PRED, start_planes, num_nodes, max_steps):
+_BFS_STATIC = ("num_nodes", "max_steps", "n_sorted")
+_CHUNK_STATIC = ("num_nodes", "chunk", "n_sorted")
+
+
+@functools.partial(jax.jit, static_argnames=_BFS_STATIC)
+def _bfs(subj, pred, obj, B, PRED, start_planes, num_nodes: int,
+         max_steps: int, off=None, n_sorted: int = 0):
+    """Single-frontier BFS.  start_planes: [V, S] int8.  Returns visited
+    [V, S] (int8) after convergence (or max_steps), and the trips."""
+    _, visited, it = _bfs_loop(subj, pred, obj, B, PRED, start_planes,
+                               start_planes, num_nodes, max_steps, off,
+                               n_sorted)
+    return visited, it
+
+
+@functools.partial(jax.jit, static_argnames=_BFS_STATIC)
+def _bfs_batched(subj, pred, obj, B, PRED, start_planes, num_nodes,
+                 max_steps, off=None, n_sorted: int = 0):
     """start_planes: [Bsrc, V, S] — multi-source batched BFS (vmapped)."""
     run = jax.vmap(
-        lambda sp: _bfs_inner(subj, pred, obj, B, PRED, sp, num_nodes, max_steps)
+        lambda sp: _bfs_loop(subj, pred, obj, B, PRED, sp, sp, num_nodes,
+                             max_steps, off, n_sorted)[1]
     )
     return run(start_planes)
-
-
-def _bfs_inner(subj, pred, obj, B, PRED, start_planes, num_nodes, max_steps):
-    def step(state):
-        frontier, visited, it = state
-        new, vis = _step_core(subj, pred, obj, B, PRED, frontier, visited,
-                              num_nodes)
-        return new, vis, it + 1
-
-    def cond(state):
-        frontier, _, it = state
-        return jnp.logical_and(jnp.any(frontier > 0), it < max_steps)
-
-    out = jax.lax.while_loop(cond, step, (start_planes, start_planes, jnp.int32(0)))
-    return out[1]
 
 
 # -- deadline-steppable variants: a compiled CHUNK of supersteps (its own
@@ -210,53 +271,39 @@ def _bfs_inner(subj, pred, obj, B, PRED, start_planes, num_nodes, max_steps):
 _DEADLINE_CHUNK = 16
 
 
-def _chunk_inner(subj, pred, obj, B, PRED, frontier, visited, num_nodes,
-                 chunk):
-    def step(state):
-        f, v, it = state
-        new, vis = _step_core(subj, pred, obj, B, PRED, f, v, num_nodes)
-        return new, vis, it + 1
-
-    def cond(state):
-        f, _, it = state
-        return jnp.logical_and(jnp.any(f > 0), it < chunk)
-
-    return jax.lax.while_loop(cond, step,
-                              (frontier, visited, jnp.int32(0)))
-
-
-@functools.partial(jax.jit, static_argnames=("num_nodes", "chunk"))
+@functools.partial(jax.jit, static_argnames=_CHUNK_STATIC)
 def _bfs_chunk(subj, pred, obj, B, PRED, frontier, visited, num_nodes,
-               chunk):
-    return _chunk_inner(subj, pred, obj, B, PRED, frontier, visited,
-                        num_nodes, chunk)
+               chunk, off=None, n_sorted: int = 0):
+    return _bfs_loop(subj, pred, obj, B, PRED, frontier, visited,
+                     num_nodes, chunk, off, n_sorted)
 
 
-@functools.partial(jax.jit, static_argnames=("num_nodes", "chunk"))
+@functools.partial(jax.jit, static_argnames=_CHUNK_STATIC)
 def _bfs_chunk_batched(subj, pred, obj, B, PRED, frontier, visited,
-                       num_nodes, chunk):
+                       num_nodes, chunk, off=None, n_sorted: int = 0):
     run = jax.vmap(
-        lambda f, v: _chunk_inner(subj, pred, obj, B, PRED, f, v,
-                                  num_nodes, chunk)
+        lambda f, v: _bfs_loop(subj, pred, obj, B, PRED, f, v, num_nodes,
+                               chunk, off, n_sorted)
     )
     f, v, its = run(frontier, visited)
     return f, v, jnp.max(its)
 
 
-@functools.partial(jax.jit, static_argnames=("num_nodes", "chunk"))
+@functools.partial(jax.jit, static_argnames=_CHUNK_STATIC)
 def _bfs_chunk_hetero(subj, pred, obj, Bstk, PREDstk, frontier, visited,
-                      num_nodes, chunk):
+                      num_nodes, chunk, off=None, n_sorted: int = 0):
     run = jax.vmap(
-        lambda B, PRED, f, v: _chunk_inner(subj, pred, obj, B, PRED, f, v,
-                                           num_nodes, chunk)
+        lambda B, PRED, f, v: _bfs_loop(subj, pred, obj, B, PRED, f, v,
+                                        num_nodes, chunk, off, n_sorted)
     )
     f, v, its = run(Bstk, PREDstk, frontier, visited)
     return f, v, jnp.max(its)
 
 
-def _host_stepped(chunk_fn, tables, start_planes, num_nodes, max_steps,
-                  deadline, collector=None):
-    """Drive compiled superstep chunks from the host, checking
+def _host_stepped(chunk_fn, edges: EdgeSet, tables, start_planes,
+                  num_nodes, max_steps, deadline, collector=None):
+    """Drive compiled superstep chunks over ``edges`` with the plane
+    tables ``tables`` = (B, PRED) from the host, checking
     ``deadline`` (absolute seconds) between chunks — raises the same
     ``TimeoutError`` the ring engine uses.  Returns (visited, steps).
     The fixed chunk size keeps compiled shapes stable; overshooting
@@ -278,9 +325,13 @@ def _host_stepped(chunk_fn, tables, start_planes, num_nodes, max_steps,
         if collector is not None:
             fin = int((frontier > 0).sum())   # repro: noqa R002 — ANALYZE-only sync
             vin = int((visited > 0).sum())    # repro: noqa R002 — ANALYZE-only sync
-        with otrace.span("dense.bfs_chunk", cat="kernel", steps=steps):
+        with otrace.span("dense.bfs_chunk", cat="kernel", steps=steps,
+                         sorted_rows=edges.n_sorted,
+                         tail_rows=edges.tail_rows):
             frontier, visited, done = chunk_fn(
-                *tables, frontier, visited, num_nodes, steps)
+                edges.subj, edges.pred, edges.obj, *tables, frontier,
+                visited, num_nodes, steps, off=edges.off,
+                n_sorted=edges.n_sorted)
             if collector is not None:
                 # block inside the span so kernel_ms covers the dispatch
                 done = int(done)              # repro: noqa R002 — ANALYZE-only sync
@@ -296,15 +347,16 @@ def _host_stepped(chunk_fn, tables, start_planes, num_nodes, max_steps,
     return visited, it
 
 
-@functools.partial(jax.jit, static_argnames=("num_nodes", "max_steps"))
+@functools.partial(jax.jit, static_argnames=_BFS_STATIC)
 def _bfs_hetero(subj, pred, obj, Bstk, PREDstk, start_planes, num_nodes,
-                max_steps):
+                max_steps, off=None, n_sorted: int = 0):
     """Heterogeneous-plan batched BFS: row r runs its OWN automaton.
     Bstk: [R, L, S_pad], PREDstk: [R, S_pad, S_pad],
     start_planes: [R, V, S_pad] — one vmap over (tables, sources)."""
     run = jax.vmap(
-        lambda B, PRED, sp: _bfs_inner(subj, pred, obj, B, PRED, sp,
-                                       num_nodes, max_steps)
+        lambda B, PRED, sp: _bfs_loop(subj, pred, obj, B, PRED, sp, sp,
+                                      num_nodes, max_steps, off,
+                                      n_sorted)[1]
     )
     return run(Bstk, PREDstk, start_planes)
 
@@ -374,7 +426,7 @@ class DenseRPQ(dl.LiveUpdateEngine):
         self.delta: Optional[dl.DeltaOverlay] = None  # live-update overlay
         self.compact_threshold = compact_threshold
         self.compactions = 0
-        self._eff = None            # (subj, pred, obj) with overlay applied
+        self._eff: Optional[EdgeSet] = None  # rows with overlay applied
         self._stats = stats
         self._edge_s: Optional[np.ndarray] = None   # completed edges,
         self._edge_o: Optional[np.ndarray] = None   # label-major order
@@ -418,7 +470,9 @@ class DenseRPQ(dl.LiveUpdateEngine):
         B row is all-zero, so they can never fire — and the overlay's
         insert buffer is appended as extra edge rows (padded to a power
         of two so compiled BFS shapes are reused while the buffer
-        grows).  A mesh-sharded engine re-partitions the same arrays."""
+        grows).  Both keep the base rows in place, so the base's segment
+        offsets still describe the sorted prefix, and only the buffer is
+        scattered.  A mesh-sharded engine re-partitions the same arrays."""
         ov = self.delta
         self._edge_eff = {}
         subj = np.asarray(self.dg.subj, dtype=np.int32)
@@ -442,8 +496,9 @@ class DenseRPQ(dl.LiveUpdateEngine):
             subj = np.concatenate([subj, pad_s])
             pred = np.concatenate([pred, pad_p])
             obj = np.concatenate([obj, pad_o])
-            self._eff = (jnp.asarray(subj), jnp.asarray(pred),
-                         jnp.asarray(obj))
+            self._eff = EdgeSet(jnp.asarray(subj), jnp.asarray(pred),
+                                jnp.asarray(obj), self.dg.off,
+                                int(self.dg.subj.shape[0]))
         else:
             self._eff = None
         if self.sharded is not None:
@@ -452,11 +507,10 @@ class DenseRPQ(dl.LiveUpdateEngine):
                 subj=subj, pred=pred, obj=obj,
                 num_nodes=self.dg.num_nodes, num_labels=L))
 
-    def _edges(self):
-        """The (subj, pred, obj) device arrays every BFS runs over —
-        the effective set when an overlay is live, else the base."""
-        return self._eff if self._eff is not None \
-            else (self.dg.subj, self.dg.pred, self.dg.obj)
+    def _edges(self) -> EdgeSet:
+        """The edge rows every BFS runs over — the effective set when an
+        overlay is live, else the base."""
+        return self._eff if self._eff is not None else self.dg.edges
 
     def compact(self) -> None:
         """Fold the overlay into a fresh base graph + plane arrays.
@@ -579,7 +633,7 @@ class DenseRPQ(dl.LiveUpdateEngine):
         g = plan.g
         if g.F & ~1 == 0:
             return np.zeros(V, dtype=bool)
-        subj, pred, obj = self._edges()
+        edges = self._edges()
         max_steps = V * (g.m + 1) + 1
         # ANALYZE routes to the host-stepped loop (chunk=1, per-superstep
         # collector) even when sharded — results are identical (the
@@ -598,7 +652,7 @@ class DenseRPQ(dl.LiveUpdateEngine):
         if self._deadline is not None or self._analyze is not None:
             self.traces.record("bfs_chunk", V, g.m + 1)
             visited, it = _host_stepped(
-                _bfs_chunk, (subj, pred, obj, plan.B, plan.PRED),
+                _bfs_chunk, edges, (plan.B, plan.PRED),
                 self._start_planes(g, objs), V, max_steps, self._deadline,
                 collector=self._analyze,
             )
@@ -606,9 +660,10 @@ class DenseRPQ(dl.LiveUpdateEngine):
             return np.asarray(visited[:, 0]) > 0
         self.traces.record("bfs", V, g.m + 1, max_steps)
         visited, _ = _bfs(
-            subj, pred, obj, plan.B, plan.PRED,
+            edges.subj, edges.pred, edges.obj, plan.B, plan.PRED,
             jnp.asarray(self._start_planes(g, objs)),
-            num_nodes=V, max_steps=max_steps,
+            num_nodes=V, max_steps=max_steps, off=edges.off,
+            n_sorted=edges.n_sorted,
         )
         return np.asarray(visited[:, 0]) > 0
 
@@ -621,7 +676,7 @@ class DenseRPQ(dl.LiveUpdateEngine):
         hits = np.zeros((len(starts), V), dtype=bool)
         if g.F & ~1 == 0 or not len(starts):
             return hits
-        subj, pred, obj = self._edges()
+        edges = self._edges()
         Bsz = batch_size or self.source_batch
         S = g.m + 1
         frow = _start_row(g)
@@ -652,8 +707,7 @@ class DenseRPQ(dl.LiveUpdateEngine):
             if self._deadline is not None or self._analyze is not None:
                 self.traces.record("bfs_chunk_batched", len(chunk), V, S)
                 visited, it = _host_stepped(
-                    _bfs_chunk_batched,
-                    (subj, pred, obj, plan.B, plan.PRED),
+                    _bfs_chunk_batched, edges, (plan.B, plan.PRED),
                     planes, V, V * S + 1, self._deadline,
                     collector=self._analyze,
                 )
@@ -661,8 +715,9 @@ class DenseRPQ(dl.LiveUpdateEngine):
             else:
                 self.traces.record("bfs_batched", len(chunk), V, S)
                 visited = _bfs_batched(
-                    subj, pred, obj, plan.B, plan.PRED,
-                    jnp.asarray(planes), V, V * S + 1,
+                    edges.subj, edges.pred, edges.obj, plan.B, plan.PRED,
+                    jnp.asarray(planes), V, V * S + 1, off=edges.off,
+                    n_sorted=edges.n_sorted,
                 )
             hits[i : i + len(chunk)] = np.asarray(visited[:, :, 0]) > 0
         return hits
@@ -693,7 +748,7 @@ class DenseRPQ(dl.LiveUpdateEngine):
         hits = np.zeros((len(rows), V), dtype=bool)
         if not rows:
             return hits
-        subj, pred, obj = self._edges()
+        edges = self._edges()
         L = self.dg.num_labels
         Bsz = batch_size or self.source_batch
         buckets: Dict[int, List[int]] = {}
@@ -727,9 +782,8 @@ class DenseRPQ(dl.LiveUpdateEngine):
                 elif self._deadline is not None or self._analyze is not None:
                     self.traces.record("bfs_chunk_hetero", Bsz, S_pad)
                     visited, it = _host_stepped(
-                        _bfs_chunk_hetero,
-                        (subj, pred, obj, jnp.asarray(Bstk),
-                         jnp.asarray(PREDstk)),
+                        _bfs_chunk_hetero, edges,
+                        (jnp.asarray(Bstk), jnp.asarray(PREDstk)),
                         planes, V, V * S_pad + 1, self._deadline,
                         collector=self._analyze,
                     )
@@ -737,9 +791,10 @@ class DenseRPQ(dl.LiveUpdateEngine):
                 else:
                     self.traces.record("bfs_hetero", Bsz, S_pad)
                     visited = _bfs_hetero(
-                        subj, pred, obj, jnp.asarray(Bstk),
+                        edges.subj, edges.pred, edges.obj, jnp.asarray(Bstk),
                         jnp.asarray(PREDstk), jnp.asarray(planes),
-                        V, V * S_pad + 1,
+                        V, V * S_pad + 1, off=edges.off,
+                        n_sorted=edges.n_sorted,
                     )
                 self.hetero_dispatches += 1
                 vis0 = np.asarray(visited[:R, :, 0]) > 0
@@ -1052,8 +1107,8 @@ class _DenseSlot:
     __slots__ = ("plan", "start", "edges", "S_pad", "frontier", "visited",
                  "active")
 
-    def __init__(self, plan: _DensePlan, start: int, edges, S_pad: int,
-                 num_nodes: int):
+    def __init__(self, plan: _DensePlan, start: int, edges: EdgeSet,
+                 S_pad: int, num_nodes: int):
         self.plan = plan
         self.start = start
         self.edges = edges
@@ -1083,8 +1138,8 @@ class DenseStepper:
     plane) only ever grows, which makes incremental result streaming
     sound.
 
-    Version snapshots: ``add_job`` pins the (subj, pred, obj) arrays
-    the slot's BFS reads.  ``submit_update`` builds the next epoch's
+    Version snapshots: ``add_job`` pins the :class:`EdgeSet` the slot's
+    BFS reads.  ``submit_update`` builds the next epoch's
     effective arrays OFF TO THE SIDE (``_on_overlay_change`` constructs
     fresh arrays, never mutating old ones), so in-flight slots keep
     reading their admission epoch — at most two snapshots are live at
@@ -1096,15 +1151,15 @@ class DenseStepper:
         self.steps_per_tick = max(1, int(steps_per_tick))
         self.slots: List[_DenseSlot] = []
         # per edge snapshot (keyed like step()'s groups, holding the
-        # arrays so the ids stay theirs): in-degree over live labels
-        self._indeg: Dict[Tuple, Tuple[Tuple, np.ndarray]] = {}
+        # snapshot so the id stays its own): in-degree over live labels
+        self._indeg: Dict[int, Tuple[EdgeSet, np.ndarray]] = {}
 
     # -- admission / retirement --------------------------------------------
     def add_job(self, plan: _DensePlan, start: int,
-                edges=None) -> _DenseSlot:
+                edges: Optional[EdgeSet] = None) -> _DenseSlot:
         """Admit one backward BFS from ``start`` (before the next tick).
-        ``edges`` pins the (subj, pred, obj) snapshot; default = the
-        engine's current effective arrays."""
+        ``edges`` pins the edge snapshot; default = the engine's current
+        effective rows."""
         eng = self.eng
         edges = edges if edges is not None else eng._edges()
         slot = _DenseSlot(plan, int(start), edges,
@@ -1138,10 +1193,10 @@ class DenseStepper:
         too, so for a slot retired on a hit this is an upper bound by
         one frontier.  Costs a pass over the planes (and, once per edge
         snapshot, a download of its label and object arrays)."""
-        key = tuple(id(a) for a in slot.edges)
+        key = id(slot.edges)
         if key not in self._indeg:
-            _subj, pred, obj = slot.edges
-            pred, obj = np.asarray(pred), np.asarray(obj)
+            pred = np.asarray(slot.edges.pred)
+            obj = np.asarray(slot.edges.obj)
             if len(self._indeg) >= 4:      # at most two snapshots are live
                 self._indeg.clear()
             self._indeg[key] = (slot.edges, np.bincount(
@@ -1161,7 +1216,7 @@ class DenseStepper:
         groups: Dict[Tuple, List[_DenseSlot]] = {}
         for slot in self.slots:
             if slot.active:
-                key = (tuple(id(a) for a in slot.edges), slot.S_pad)
+                key = (id(slot.edges), slot.S_pad)
                 groups.setdefault(key, []).append(slot)
         with otrace.span("dense.superstep", cat="engine",
                          slots=len(self.slots), groups=len(groups)):
@@ -1188,16 +1243,20 @@ class DenseStepper:
                 with otrace.span("dense.upload", cat="transfer", bytes=h2d):
                     planes = (jnp.asarray(Bstk), jnp.asarray(PREDstk),
                               jnp.asarray(front), jnp.asarray(vis))
-                subj, pred, obj = members[0].edges
+                edges = members[0].edges
                 eng.traces.record("bfs_chunk_hetero", C, S_pad)
                 # the device wait is the block inside this span; the
                 # download below then copies finished buffers
                 with otrace.span("dense.bfs_chunk", cat="kernel", rows=C,
                                  live=len(members), width=S_pad,
-                                 swept=C * int(subj.shape[0]) * S_pad
-                                 * self.steps_per_tick):
-                    out = _bfs_chunk_hetero(subj, pred, obj, *planes, V,
-                                            self.steps_per_tick)
+                                 swept=C * int(edges.subj.shape[0]) * S_pad
+                                 * self.steps_per_tick,
+                                 sorted_rows=edges.n_sorted,
+                                 tail_rows=edges.tail_rows):
+                    out = _bfs_chunk_hetero(
+                        edges.subj, edges.pred, edges.obj, *planes, V,
+                        self.steps_per_tick, off=edges.off,
+                        n_sorted=edges.n_sorted)
                     jax.block_until_ready(out)
                 eng.hetero_dispatches += 1
                 eng.h2d_bytes += h2d

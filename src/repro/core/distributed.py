@@ -147,9 +147,11 @@ def _local_bfs_step(frontier, frontier_l, visited_l, subj_l, pred_l, obj_l,
                     B, PRED, model_axis: Optional[str]):
     """One shard's superstep body on an already-gathered frontier [V, S]:
     the single-device edge scatter (``dense._edge_scatter`` — one source
-    of truth for the step math) targeting only the shard's local rows,
-    then an optional psum-OR over the model axis when the shard's edges
-    are model-split (0/1 counts, then >0), then the visited merge."""
+    of truth for the step math) targeting only the shard's local rows;
+    it passes no segment offsets, so every row of the shard takes the
+    scatter.  Then an optional psum-OR over the model axis when the
+    shard's edges are model-split (0/1 counts, then >0), then the
+    visited merge."""
     from .dense import _edge_scatter
     scat = _edge_scatter(subj_l, pred_l, obj_l, B, PRED, frontier,
                          frontier_l.shape[0])

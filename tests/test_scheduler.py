@@ -420,6 +420,44 @@ def test_dense_tick_spans_nest_inside_the_tick():
     assert in_harvest and all("useful" in e["args"] for e in in_harvest)
 
 
+@pytest.mark.parametrize("live", [False, True], ids=["read-only",
+                                                     "overlay"])
+def test_dense_chunk_span_counts_sorted_and_tail_rows(live):
+    """``dense.bfs_chunk`` says how many edge rows its superstep reduced
+    without a scatter (``sorted_rows``) and how many it still scattered
+    (``tail_rows``): together the rows swept.  A read-only engine
+    scatters none; a live overlay scatters its insert buffer only, and
+    the answers stay the oracle's."""
+    from repro.obs import trace as otrace
+    g = random_graph(12, 3, 40, seed=6, pred_zipf=False)
+    eng = make_engine(g, "dense")
+    if live:
+        eng.add_edges([(0, 0, 5), (3, 1, 7), (11, 2, 0)])
+        eng.remove_edges([(int(g.s[0]), int(g.p[0]), int(g.o[0]))])
+    queries = [Query("0/1*", obj=3), Query("(0|1)/2", subject=2),
+               Query("2+", subject=1, obj=4), Query("^1/0*", obj=5)]
+    tr = otrace.Tracer()
+    tr.enable()
+    with otrace.use(tr):
+        sched = SlotScheduler(eng, max_slots=3)
+        tickets = [sched.submit(q) for q in queries]
+        sched.drain()
+    E_base = int(eng.dg.subj.shape[0])
+    E = int(eng._edges().subj.shape[0])
+    assert (E > E_base) == live
+    chunks = [e["args"] for e in tr.events if e["name"] == "dense.bfs_chunk"]
+    assert chunks
+    for c in chunks:
+        assert c["sorted_rows"] == E_base
+        assert c["tail_rows"] == E - E_base
+        assert c["swept"] == c["rows"] * (c["sorted_rows"] + c["tail_rows"]) \
+            * c["width"]
+        assert (c["tail_rows"] > 0) == live
+    eff = eng.effective_graph()
+    for q, t in zip(queries, tickets):
+        assert t.result() == eval_oracle(eff, q.expr, q.subject, q.obj), q
+
+
 def test_admit_and_retire_spans_share_a_request_id():
     from repro.obs import trace as otrace
     g = random_graph(12, 3, 40, seed=6, pred_zipf=False)

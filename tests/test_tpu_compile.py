@@ -22,6 +22,9 @@ HBM_BYTES = 15.75e9   # what the compiler lets one v5e program use
 # the dense smoke deployment: scale_free_graph(2**20, 64, 2**22) has at
 # most 2**23 completed edges over 128 completed labels
 SMOKE_V, SMOKE_E, SMOKE_L = 2**20, 2**23, 128
+# the wikidata-kg deployment: 8,375,130 completed edges over 1,526,402
+# nodes and 10,838 completed labels (plus the inert label row)
+KG_V, KG_E, KG_L = 1_526_402, 8_375_130, 10_838
 
 
 @pytest.fixture(scope="module")
@@ -68,22 +71,28 @@ def test_rank_window_compiles(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_dense_chunk_fits_one_chip(one_chip):
-    """The slot tick's program at the smoke deployment's size, 8 rows of
-    16-state planes, within one chip's HBM."""
-    C, S = 8, 16
-    edge = _sds((SMOKE_E,), jnp.int32, one_chip)
+@pytest.mark.parametrize("V,E,L,C,S", [
+    (SMOKE_V, SMOKE_E, SMOKE_L, 8, 16),
+    (KG_V, KG_E, KG_L, 8, 8),
+], ids=["smoke", "wikidata-kg"])
+def test_dense_chunk_fits_one_chip(one_chip, V, E, L, C, S):
+    """The slot tick's program, C rows of S-state planes over E sorted
+    edges with no overlay, within one chip's HBM — and with no scatter
+    left: the segment-OR runs over the sorted edges."""
+    edge = _sds((E,), jnp.int32, one_chip)
     compiled = dense._bfs_chunk_hetero.lower(
         edge, edge, edge,
-        _sds((C, SMOKE_L + 1, S), jnp.int8, one_chip),
+        _sds((C, L + 1, S), jnp.int8, one_chip),
         _sds((C, S, S), jnp.int8, one_chip),
-        _sds((C, SMOKE_V, S), jnp.int8, one_chip),
-        _sds((C, SMOKE_V, S), jnp.int8, one_chip),
-        num_nodes=SMOKE_V, chunk=1).compile()
+        _sds((C, V, S), jnp.int8, one_chip),
+        _sds((C, V, S), jnp.int8, one_chip),
+        num_nodes=V, chunk=1, off=_sds((V + 1,), jnp.int32, one_chip),
+        n_sorted=E).compile()
     mem = compiled.memory_analysis()
     total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
              + mem.output_size_in_bytes)
     assert 0 < total < HBM_BYTES, total
+    assert "scatter(" not in compiled.as_text()
 
 
 def test_segmented_or_scan_refused(one_chip):
