@@ -7,19 +7,26 @@
   long the queue took to drain after the close;
 * the readings that set the limits of ``correct`` — one window per seed
   at the mix's frozen rate, with the program's answers and, with
-  ``--control``, the control's (the reference with every closure one
-  level short of its fixpoint) compared against the reference on the
-  same sampled requests.
+  ``--control``, the control's (the mix's ``check.control``: by default
+  the reference with every closure one level short of its fixpoint)
+  compared against the reference on the same sampled requests.
+
+A mix with ``writes`` sends them in every window, at its ratio to the
+window's read rate.  ``--fault`` plants one of ``tpubench.faults``'
+write-path faults under the served path before the first window, to
+read the checks that should catch it at the cell's own load.
 
     python3 benchmarks/tpu/prove.py --workload kg-steady --seconds 51 \\
         --rates 0.3,0.5,0.8
     python3 benchmarks/tpu/prove.py --workload kg-steady --seconds 51 \\
         --seeds 11,12,13 --control
+    python3 benchmarks/tpu/prove.py --workload snb-writes --seconds 51 \\
+        --seeds 21,22,23 --fault read_at_newest
 
 Every window gets a served system of its own, built and warmed up as a
-run's set-up is: a window on a system that has served the pool before
-would find its answers in the result cache.  Prints one JSON line per
-window.
+run's set-up is (``harness.serve``): a window on a system that has
+served the pool before would find its answers in the result cache.
+Prints one JSON line per window.
 """
 import time
 
@@ -77,8 +84,7 @@ def summary(cell, out):
 
 
 async def prove(args):
-    from tpubench import harness, spec
-    from tpubench import traffic as tr
+    from tpubench import faults, harness, spec
     cell = spec.resolve(args.workload)
     devs = harness.check_devices(cell.chips)
     print(f"device: {devs[0].device_kind} x{len(devs)}", file=sys.stderr)
@@ -86,18 +92,23 @@ async def prove(args):
     graph = harness.reference_graph(data)
     items = [("rate", r) for r in _floats(args.rates)] \
         + [("seed", s) for s in _ints(args.seeds)]
+    planted = []
+
+    def plant():
+        if args.fault and not planted:
+            faults.FAULTS[args.fault]()
+            planted.append(args.fault)
+
     for kind, v in items:
         seed = int(v) if kind == "seed" else SWEEP_SEED
         rate = float(v) if kind == "rate" else None
-        plan = tr.window_plan(cell.mix, data, args.seconds, seed, graph,
-                              rate=rate)
-        sess = harness.Session(cell, data, time.monotonic())
-        await sess.open(plan.cached)
-        win, _ = await sess.window(plan, args.seconds)
-        settled = all(r.error is None for r in win.records)
-        out = harness.Outcome(sess.setup_s, win, 0, graph)
-        row = {"workload": cell.name, kind: v, "setup_s": sess.setup_s,
-               **summary(cell, out)}
+        out = await harness.serve(cell, data, seed, args.seconds, None,
+                                  time.monotonic(), rate=rate, graph=graph,
+                                  before_window=plant)
+        settled = all(r.error is None for r in out.win.records)
+        row = {"workload": cell.name, kind: v, "fault": args.fault,
+               "setup_s": out.setup_s,
+               "memory_peak_bytes": out.peak_bytes, **summary(cell, out)}
         t0 = time.monotonic()
         row["checks"] = harness.check(cell, data, out, seed)
         if args.control:
@@ -107,7 +118,6 @@ async def prove(args):
         print(json.dumps(row), flush=True)
         if not settled:
             return 1        # a request never settled: the pump cannot stop
-        await sess.close(True)
     return 0
 
 
@@ -118,6 +128,9 @@ def main(argv=None) -> int:
     ap.add_argument("--rates", default="")
     ap.add_argument("--seeds", default="")
     ap.add_argument("--control", action="store_true")
+    ap.add_argument("--fault", default=None,
+                    choices=["ack_without_apply", "read_at_newest",
+                             "admission_lags"])
     args = ap.parse_args(argv)
     from tpubench import harness
     harness.setup_env()

@@ -22,6 +22,19 @@ A run:
 4. waits for every answer (at most ``drain_s`` past the close), reads the
    device's peak memory, frees the program's state, and checks a sample
    of the answers against the plain reference.
+
+A mix with ``writes`` also sends the configuration's update stream
+through ``AsyncServer.submit_update``: set-up applies the stream's head
+as one batch before it warms up, and in the window a writer coroutine
+beside the pump applies the inserts that fell due in each
+``commit_s`` seconds as one batch, each insert timed from its due time
+to the return of the call that carried it.  Batches are numbered from 1
+in the order they were applied, the set-up's first; each answer is
+checked against the reference at its ticket's epoch (the base and the
+first ``epoch`` batches), every read is checked to have been admitted at
+an epoch no lower than the batches acknowledged before it was
+submitted, and after the window a sample of the acknowledged inserts is
+read back through the served path.
 """
 from __future__ import annotations
 
@@ -59,6 +72,54 @@ class Record:
     cache_hit: bool = False
     plan: str = ""
     error: Optional[str] = None
+    epoch: Optional[int] = None     # the ticket's admission epoch
+    min_epoch: int = 0              # last epoch acknowledged at submit
+
+
+class Writes:
+    """The write side of a run: the plan, and the batches applied so
+    far, each a range ``[a, b)`` of stream operations, with the epoch
+    its call returned and, for the window's operations, when it
+    returned."""
+
+    def __init__(self, plan: tr.WritePlan, stream: Dict[str, Any]):
+        self.plan, self.stream = plan, stream
+        self.batches: List[Tuple[int, int]] = []
+        self.acks: List[int] = []
+        self.ack_times: List[float] = []
+        self.acked_at = np.full(plan.due.size, np.nan)
+        self.error: Optional[str] = None
+
+    def apply(self, server, a: int, b: int) -> None:
+        s, p, o = tr.stream_edges(self.stream, a, b)
+        try:
+            epoch = server.submit_update(
+                add=list(zip(s.tolist(), p.tolist(), o.tolist())))
+        except Exception as e:      # noqa: BLE001 — recorded as a failure
+            self.error = f"{type(e).__name__}: {e}"
+            return
+        now = time.monotonic()
+        self.batches.append((a, b))
+        self.acks.append(int(epoch))
+        self.ack_times.append(now)
+        lo, hi = max(a, self.plan.head), b
+        if hi > lo:
+            self.acked_at[lo - self.plan.head:hi - self.plan.head] = now
+
+    def latencies_ms(self, t0: float) -> np.ndarray:
+        """Each acknowledged window insert, from due time to the return
+        of the call that carried it."""
+        ok = ~np.isnan(self.acked_at)
+        return (self.acked_at[ok] - t0 - self.plan.due[ok]) * 1e3
+
+    def edge_epochs(self) -> np.ndarray:
+        """Per stream edge: the number of the batch that applied it, or
+        ``NEVER``."""
+        off = self.stream["off"]
+        ep = np.full(int(off[-1]), ref.NEVER, dtype=np.int64)
+        for j, (a, b) in enumerate(self.batches, start=1):
+            ep[off[a]:off[b]] = j
+        return ep
 
 
 @dataclass
@@ -67,6 +128,7 @@ class Window:
     seconds: float
     records: List[Record]
     compiles: List[str] = field(default_factory=list)
+    writes: Optional[Writes] = None
 
 
 class CompileLog:
@@ -139,10 +201,13 @@ async def _burst(server, reqs: List[tr.Request]) -> None:
         await t.result()
 
 
-async def _request(server, rec: Record, t0: float) -> None:
+async def _request(server, rec: Record, t0: float,
+                   writes: Optional[Writes] = None) -> None:
     from repro.core.scheduler import Backpressure
     await _sleep_until(t0 + rec.req.due)
     rec.submitted = time.monotonic()
+    if writes is not None and writes.acks:
+        rec.min_epoch = writes.acks[-1]
     try:
         at = await server.submit(_query(rec.req))
     except Backpressure:
@@ -155,15 +220,34 @@ async def _request(server, rec: Record, t0: float) -> None:
     rec.done = time.monotonic()
     rec.cache_hit = at.ticket.stats.result_cache_hits > 0
     rec.plan = at.ticket.stats.plan_mode or ""
+    rec.epoch = at.ticket.epoch
+
+
+async def _writer(server, writes: Writes, t0: float) -> None:
+    """Every ``commit_s`` seconds, one batch of the operations that fell
+    due before then (none: no call)."""
+    plan = writes.plan
+    i, k = plan.head, 1
+    while i < plan.end:
+        t = k * plan.commit_s
+        await _sleep_until(t0 + t)
+        j = plan.head + int(np.searchsorted(plan.due, t, side="left"))
+        if j > i:
+            writes.apply(server, i, j)
+            i = j
+        k += 1
 
 
 async def _window(server, plan: tr.Plan, seconds: float, drain_s: float,
                   compiles: CompileLog, on_start=None,
-                  on_close=None) -> Window:
+                  on_close=None, writes: Optional[Writes] = None) -> Window:
     t0 = time.monotonic() + 0.05
-    win = Window(t0, seconds, [Record(r) for r in plan.requests])
-    tasks = [asyncio.ensure_future(_request(server, rec, t0))
+    win = Window(t0, seconds, [Record(r) for r in plan.requests],
+                 writes=writes)
+    tasks = [asyncio.ensure_future(_request(server, rec, t0, writes))
              for rec in win.records]
+    if writes is not None:
+        tasks.append(asyncio.ensure_future(_writer(server, writes, t0)))
     await _sleep_until(t0)
     if on_start is not None:
         on_start()
@@ -192,6 +276,8 @@ class Outcome:
     graph: ref.Graph
     trace: Optional[xplane.Trace] = None
     trace_window: Optional[Tuple[int, int]] = None
+    # inserts read back after the window: (s, p, o) -> seen at the end
+    read_back: Optional[Dict[Tuple[int, int, int], bool]] = None
 
 
 class Profile:
@@ -255,7 +341,8 @@ class Session:
         self.compiles = CompileLog()
         self.setup_s = 0.0
 
-    async def open(self, cached: List[tr.Request] = ()) -> None:
+    async def open(self, cached: List[tr.Request] = (),
+                   writes: Optional[Writes] = None) -> None:
         from repro.core.engines import make_engine
         from repro.core.scheduler import AsyncServer, SlotScheduler
         e = self.cell.config["engine"]
@@ -264,20 +351,62 @@ class Session:
         self.server = AsyncServer(SlotScheduler(engine,
                                                 max_slots=e["max_slots"]))
         await self.server.__aenter__()
-        for burst in tr.warmup_bursts(self.mix, self.data, e["max_slots"]):
+        data = self.data
+        if writes is not None:
+            # the window's edge rows, then warm-up at nodes that no
+            # insert of the run touches
+            writes.apply(self.server, 0, writes.plan.head)
+            s, _, o = tr.stream_edges(data["stream"], 0, writes.plan.end)
+            data = dict(data, s=np.concatenate([data["s"], s]),
+                        o=np.concatenate([data["o"], o]))
+        for burst in tr.warmup_bursts(self.mix, data, e["max_slots"]):
             await _burst(self.server, burst)
         await _burst(self.server, list(cached))
         self.setup_s = time.monotonic() - self.started
 
     async def window(self, plan: tr.Plan, seconds: float,
                      trace_dir: Optional[str] = None,
-                     drain_s: float = DRAIN_S) -> Tuple[Window, "Profile"]:
+                     drain_s: float = DRAIN_S,
+                     writes: Optional[Writes] = None
+                     ) -> Tuple[Window, "Profile"]:
         prof = Profile(trace_dir)
         prof.start()
         win = await _window(self.server, plan, seconds, drain_s,
                              self.compiles, prof.open_window,
-                             prof.close_window)
+                             prof.close_window, writes)
         return win, prof
+
+    async def read_back(self, writes: Writes, k: int, seed: int,
+                        timeout_s: float = DRAIN_S
+                        ) -> Dict[Tuple[int, int, int], bool]:
+        """Up to ``k`` of the window's acknowledged inserts, drawn from
+        ``seed``, each read back with a one-hop query anchored at both
+        ends; an insert whose answer lacks it, or never comes, is not
+        seen."""
+        off = writes.stream["off"]
+        rows = np.concatenate(
+            [np.arange(off[max(a, writes.plan.head)], off[b])
+             for a, b in writes.batches if b > writes.plan.head] or
+            [np.zeros(0, dtype=np.int64)])
+        pick = rows[tr.rng_for(seed, tr.READ_BACK).permutation(
+            rows.size)[:k]]
+        names = self.data.get("pred_names")
+        st = writes.stream
+        edges = [(int(st["s"][i]), int(st["p"][i]), int(st["o"][i]))
+                 for i in np.sort(pick)]
+        from repro.core.engines import Query
+        tickets = [await self.server.submit(
+            Query(names[p] if names else str(p), s, o)) for s, p, o in edges]
+
+        async def seen(at, s, o):
+            try:
+                return (s, o) in await at.result()
+            except Exception:       # noqa: BLE001 — not seen
+                return False
+        found = await asyncio.wait_for(asyncio.gather(
+            *[seen(at, s, o) for at, (s, _, o) in zip(tickets, edges)]),
+            timeout_s)
+        return dict(zip(edges, found))
 
     async def close(self, settled: bool) -> int:
         """Stop the server (when every request settled; otherwise its
@@ -296,18 +425,33 @@ class Session:
 async def serve(cell: specmod.Cell, data, seed: int, seconds: float,
                 trace_dir: Optional[str], started: float,
                 rate: Optional[float] = None,
-                drain_s: float = DRAIN_S, before_window=None) -> Outcome:
+                drain_s: float = DRAIN_S, before_window=None,
+                graph: Optional[ref.Graph] = None) -> Outcome:
     """One run's set-up and window.  ``before_window`` (a test's way to
-    plant a fault under the timed path) is called once set-up is done."""
-    graph = reference_graph(data)
+    plant a fault under the timed path) is called once set-up is done.
+    ``graph`` is the reference's graph of ``data``, when already built."""
+    wplan = tr.write_plan(cell.mix, data, seconds, rate)
+    graph = graph if graph is not None else reference_graph(data)
     plan = tr.window_plan(cell.mix, data, seconds, seed, graph, rate=rate)
+    writes = Writes(wplan, data["stream"]) if wplan is not None else None
     sess = Session(cell, data, started)
-    await sess.open(plan.cached)
+    await sess.open(plan.cached, writes)
     if before_window is not None:
         before_window()
-    win, prof = await sess.window(plan, seconds, trace_dir, drain_s)
-    peak = await sess.close(all(r.error is None for r in win.records))
-    out = Outcome(sess.setup_s, win, peak, graph)
+    win, prof = await sess.window(plan, seconds, trace_dir, drain_s,
+                                  writes)
+    settled = all(r.error is None for r in win.records)
+    read_back = None
+    if writes is not None:
+        k = int(cell.mix["check"].get("read_back", 0))
+        read_back = {}
+        if settled and writes.error is None:
+            try:
+                read_back = await sess.read_back(writes, k, seed, drain_s)
+            except asyncio.TimeoutError:
+                settled = False
+    peak = await sess.close(settled)
+    out = Outcome(sess.setup_s, win, peak, graph, read_back=read_back)
     prof.read(out)
     return out
 
@@ -316,8 +460,23 @@ async def serve(cell: specmod.Cell, data, seed: int, seconds: float,
 
 
 def reference_graph(data) -> ref.Graph:
-    return ref.Graph(data["s"], data["p"], data["o"], data["num_nodes"],
-                     data["num_preds"], pred_names=data.get("pred_names"))
+    """The reference's graph of ``data``; with an update stream, every
+    stream edge is in it too, at an epoch that a run assigns (until then
+    never), and a query that names no epoch reads the base."""
+    st = data.get("stream")
+    if st is None:
+        return ref.Graph(data["s"], data["p"], data["o"], data["num_nodes"],
+                         data["num_preds"], pred_names=data.get("pred_names"))
+    n = int(data["s"].size)
+    graph = ref.Graph(
+        np.concatenate([data["s"], st["s"]]),
+        np.concatenate([data["p"], st["p"]]),
+        np.concatenate([data["o"], st["o"]]), data["num_nodes"],
+        data["num_preds"], pred_names=data.get("pred_names"),
+        epochs=np.r_[np.zeros(n, dtype=np.int64),
+                     np.full(st["s"].size, ref.NEVER, dtype=np.int64)])
+    graph.at = 0
+    return graph
 
 
 def check(cell, data, out: Outcome, seed: int,
@@ -329,6 +488,15 @@ def check(cell, data, out: Outcome, seed: int,
     answered = [i for i, r in enumerate(recs) if r.answer is not None]
     must: List[int] = []
     seen = set()
+    k = int(cell.mix["check"]["sample"])
+    if win.writes is not None:
+        # reads in flight while a batch was applied: where snapshot
+        # isolation can fail; up to half the sample, drawn from the seed
+        acks = np.asarray(win.writes.ack_times[1:])
+        straddle = [i for i in answered if np.any(
+            (acks > recs[i].submitted) & (acks < recs[i].done))]
+        order = tr.rng_for(seed, tr.STRADDLE).permutation(len(straddle))
+        must += sorted(straddle[int(j)] for j in order[:k // 2])
     for i in answered:
         r = recs[i]
         for key in (("plan", r.plan), ("cache", r.cache_hit),
@@ -337,29 +505,60 @@ def check(cell, data, out: Outcome, seed: int,
                 seen.add(key)
                 must.append(i)
     sizes = [len(recs[i].answer) for i in answered]
-    k = int(cell.mix["check"]["sample"])
     pick = [answered[j] for j in tr.check_sample(
         len(answered), sizes, k, seed,
         must=[answered.index(i) for i in must])]
     graph = out.graph
+    writes = win.writes
+    if writes is not None:
+        n_base = graph.epochs.size - writes.edge_epochs().size
+        graph.epochs[n_base:] = writes.edge_epochs()
+    # the control: every closure one level short of its fixpoint, or,
+    # where the mix says so, the reference blind to the window's writes
+    kind = cell.mix["check"].get("control", "truncate_closures")
     wrong = 0
     for i in pick:
         r = recs[i]
-        want = ref.answer(graph, r.req.expr, r.req.subject, r.req.obj)
-        # the control: every closure one level short of its fixpoint
-        got = ref.answer(graph, r.req.expr, r.req.subject, r.req.obj,
-                         truncate_closures=True) if control else r.answer
+        q = (graph, r.req.expr, r.req.subject, r.req.obj)
+        # at the ticket's epoch (a graph without writes has no epochs)
+        want = ref.answer(*q, epoch=r.epoch)
+        if not control:
+            got = r.answer
+        elif kind == "ignore_writes":
+            got = ref.answer(*q, epoch=1)   # the base and set-up's batch
+        else:
+            got = ref.answer(*q, epoch=r.epoch, truncate_closures=True)
         ok, missing, extra = ref.pairs_equal(got, want)
         if not ok:
             wrong += 1
             print(f"check: {r.req.template} ({r.req.subject}, {r.req.expr}, "
-                  f"{r.req.obj}): {missing} pairs missing, {extra} extra",
-                  file=sys.stderr)
+                  f"{r.req.obj}) at epoch {r.epoch}: {missing} pairs "
+                  f"missing, {extra} extra", file=sys.stderr)
     # a request shed at the door (``Backpressure``) is unanswered too
     unanswered = sum(r.error is not None for r in recs)
-    return {"wrong_answers": {"value": wrong, "limit": 0,
-                              "checked": len(pick)},
-            "unanswered": {"value": unanswered, "limit": 0}}
+    checks = {"wrong_answers": {"value": wrong, "limit": 0,
+                                "checked": len(pick)},
+              "unanswered": {"value": unanswered, "limit": 0}}
+    if writes is None:
+        return checks
+    # an insert due in the window that no call acknowledged is unanswered
+    checks["unanswered"]["value"] += int(np.isnan(writes.acked_at).sum())
+    stale = [r for r in recs if r.epoch is not None
+             and r.epoch < r.min_epoch]
+    for r in stale[:5]:
+        print(f"check: {r.req.template} submitted after epoch "
+              f"{r.min_epoch} was acknowledged, admitted at epoch "
+              f"{r.epoch}", file=sys.stderr)
+    k = int(cell.mix["check"].get("read_back", 0))
+    seen = out.read_back or {}
+    # an insert that could not be read back (the server never settled)
+    # counts as lost
+    lost = sum(not v for v in seen.values()) + max(0, min(
+        k, int((~np.isnan(writes.acked_at)).sum())) - len(seen))
+    checks["stale_reads"] = {"value": len(stale), "limit": 0}
+    checks["lost_writes"] = {"value": lost, "limit": 0,
+                             "read_back": len(seen)}
+    return checks
 
 
 # -- the report ---------------------------------------------------------------
@@ -398,6 +597,8 @@ def end_to_end(cell, out: Outcome) -> Dict[str, Dict[str, Any]]:
            if r.answer is not None]
     values = {"setup_s": out.setup_s, "p50_ms": pct(lat, 50),
               "p90_ms": pct(lat, 90)}
+    if win.writes is not None:
+        values["write_p90_ms"] = pct(win.writes.latencies_ms(win.t0), 90)
     return {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
             for m in cell.end_to_end if values.get(m["name"]) is not None}
 
@@ -450,6 +651,19 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
           f"compiles in the window "
           f"{len(win.compiles)} {sorted(set(win.compiles))}",
           file=sys.stderr)
+    wr = win.writes
+    if wr is not None:
+        off = data["stream"]["off"]
+        print(f"{cell.name}: set-up applied {wr.plan.head} update "
+              f"operations ({int(off[wr.plan.head])} inserts); the window "
+              f"sent {wr.plan.due.size} of {wr.plan.asked} asked "
+              f"({int(off[wr.plan.end] - off[wr.plan.head])} inserts) in "
+              f"{len(wr.batches) - 1} batches, insert buffer "
+              f"{wr.plan.cap} rows; acknowledged epochs {wr.acks[:1]}.."
+              f"{wr.acks[-1:]}; write latency samples "
+              f"{int((~np.isnan(wr.acked_at)).sum())}"
+              + (f"; error {wr.error}" if wr.error else ""),
+              file=sys.stderr)
     checks = check(cell, data, out, seed, control=control)
     correct = all(c["value"] <= c["limit"] for c in checks.values())
     result = {

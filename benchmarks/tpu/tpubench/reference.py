@@ -21,6 +21,12 @@ of its fixpoint, the approximation a bounded superstep count would make.
 
 ``levels`` counts the levels every closure of a query takes to reach its
 fixpoint: the traffic generator's measure of a request's cost.
+
+A graph under writes gives each edge an epoch (``Graph(..., epochs=)``:
+0 for the base, ``j`` for the edges of the ``j``-th applied batch, and
+``NEVER`` for edges not applied), and a query is answered at an epoch:
+over the edges whose epoch is at most it.  ``Graph.at`` is the epoch
+used when a query names none (``None``: every edge).
 """
 from __future__ import annotations
 
@@ -160,16 +166,21 @@ def nullable(e: Expr) -> bool:
 # -- graph ------------------------------------------------------------------
 
 
+NEVER = np.iinfo(np.int64).max
+
+
 class _Index:
     """Edges grouped by one endpoint: ``keys`` sorted, ``vals`` the other
-    endpoint."""
+    endpoint, ``eids`` (when the graph has epochs) the edges' ids."""
 
-    def __init__(self, keys, vals):
+    def __init__(self, keys, vals, eids=None):
         order = np.argsort(keys, kind="stable")
         self.keys = keys[order]
         self.vals = vals[order]
+        self.eids = eids[order] if eids is not None else None
 
-    def step(self, nodes: np.ndarray) -> np.ndarray:
+    def step(self, nodes: np.ndarray, epochs: Optional[np.ndarray] = None,
+             at: Optional[int] = None) -> np.ndarray:
         lo = np.searchsorted(self.keys, nodes, "left")
         hi = np.searchsorted(self.keys, nodes, "right")
         cnt = hi - lo
@@ -177,7 +188,10 @@ class _Index:
         if total == 0:
             return np.zeros(0, dtype=np.int64)
         starts = np.repeat(lo - np.cumsum(cnt) + cnt, cnt)
-        return self.vals[starts + np.arange(total)]
+        rows = starts + np.arange(total)
+        if at is None or self.eids is None:
+            return self.vals[rows]
+        return self.vals[rows[epochs[self.eids[rows]] <= at]]
 
 
 class Graph:
@@ -185,7 +199,8 @@ class Graph:
     predicate ids; without it literals are ids."""
 
     def __init__(self, s, p, o, num_nodes: int, num_preds: int,
-                 pred_names: Optional[Sequence[str]] = None):
+                 pred_names: Optional[Sequence[str]] = None,
+                 epochs: Optional[np.ndarray] = None):
         s = np.asarray(s, dtype=np.int64)
         p = np.asarray(p, dtype=np.int64)
         o = np.asarray(o, dtype=np.int64)
@@ -193,8 +208,13 @@ class Graph:
         self.num_preds = int(num_preds)
         self.names = {n: i for i, n in enumerate(pred_names)} \
             if pred_names is not None else None
+        # per edge, in the order given; may be reassigned between runs
+        self.epochs = np.asarray(epochs, dtype=np.int64) \
+            if epochs is not None else None
+        self.at: Optional[int] = None
         order = np.argsort(p, kind="stable")
         s, p, o = s[order], p[order], o[order]
+        eids = order if epochs is not None else None
         off = np.searchsorted(p, np.arange(self.num_preds + 1))
         # by_obj[p]: object -> subjects (backward over p);
         # by_subj[p]: subject -> objects (backward over ^p)
@@ -204,24 +224,25 @@ class Graph:
             b, e = off[q], off[q + 1]
             if b == e:
                 continue
-            self.by_obj[q] = _Index(o[b:e], s[b:e])
-            self.by_subj[q] = _Index(s[b:e], o[b:e])
+            ids = eids[b:e] if eids is not None else None
+            self.by_obj[q] = _Index(o[b:e], s[b:e], ids)
+            self.by_subj[q] = _Index(s[b:e], o[b:e], ids)
 
     def pred_id(self, name: str) -> int:
         if self.names is not None and not name.isdigit():
             return self.names[name]
         return int(name)
 
-    def back_lit(self, lit: Lit, nodes: np.ndarray,
-                 forward: bool) -> np.ndarray:
+    def back_lit(self, lit: Lit, nodes: np.ndarray, forward: bool,
+                 at: Optional[int] = None) -> np.ndarray:
         """Nodes one ``lit`` edge before ``nodes`` (after them when
-        ``forward``)."""
+        ``forward``), over the edges of epoch ``at`` or earlier."""
         q = self.pred_id(lit.name)
         use_obj = (not lit.inverse) != forward
         idx = (self.by_obj if use_obj else self.by_subj).get(q)
         if idx is None or nodes.size == 0:
             return np.zeros(0, dtype=np.int64)
-        return idx.step(nodes)
+        return idx.step(nodes, self.epochs, at)
 
 
 # -- evaluation -----------------------------------------------------------
@@ -230,9 +251,11 @@ class Graph:
 class Evaluator:
     """Set-semantics evaluation over :class:`Graph`."""
 
-    def __init__(self, graph: Graph, truncate_closures: bool = False):
+    def __init__(self, graph: Graph, truncate_closures: bool = False,
+                 epoch: Optional[int] = None):
         self.g = graph
         self.truncate = truncate_closures
+        self.at = epoch if epoch is not None else graph.at
         self.levels = 0         # closure levels walked so far
 
     def _mask(self, nodes: np.ndarray) -> np.ndarray:
@@ -244,7 +267,8 @@ class Evaluator:
         """Bool mask of the nodes related to some node of mask ``T`` by
         ``e`` (reading ``e`` backward, or forward when ``forward``)."""
         if isinstance(e, Lit):
-            return self._mask(self.g.back_lit(e, np.nonzero(T)[0], forward))
+            return self._mask(self.g.back_lit(e, np.nonzero(T)[0], forward,
+                                              self.at))
         if isinstance(e, Cat):
             first, second = (e.left, e.right) if forward \
                 else (e.right, e.left)
@@ -300,8 +324,9 @@ class Evaluator:
 
 
 def answer(graph: Graph, expr: str, subject: Optional[int],
-           obj: Optional[int], truncate_closures: bool = False) -> frozenset:
-    return Evaluator(graph, truncate_closures).answer(
+           obj: Optional[int], truncate_closures: bool = False,
+           epoch: Optional[int] = None) -> frozenset:
+    return Evaluator(graph, truncate_closures, epoch).answer(
         expr, subject, obj)
 
 

@@ -19,12 +19,20 @@ A mix (``traffic/<name>.json``) holds only parameters:
 * ``--seed`` draws which request of a cost takes which of the positions
   the layout gives that cost.  So runs with different seeds send the
   same work at the same times, in another order of like requests, and
-  their spread is that of the system, not of the draw.
+  their spread is that of the system, not of the draw.  With
+  ``"alike": "template"`` in the layout, requests are alike only when
+  they share their template as well as their cost: where requests of
+  one cost differ in work the levels do not count (answer sizes, hops
+  outside closures), the seed then moves less of that work.
 * ``templates`` — query templates: ``expr`` with ``{0}``..``{3}``
   placeholders for predicates drawn from ``predicates``, or with
   predicate names; ``anchor`` is ``obj``, ``subj`` or ``both``; the
   anchor domains name node sets the configuration's generator exports;
-  ``weight`` is the template's share.
+  ``weight`` is the template's share.  Each request's template is drawn
+  with it, or, where the mix sets ``"shares": "exact"``, the i-th
+  request takes the template furthest below its share of the first i
+  requests: every prefix of the pool, and so the pool at every rate,
+  then holds each template's share to within one request.
 * ``predicates`` — ``{"draw": "zipf"}``: placeholder predicates drawn
   with weight 1/rank over the configuration's predicates.
 * ``cached`` (optional) — ``{"count": k}``: set-up serves the ``k`` pool
@@ -32,7 +40,18 @@ A mix (``traffic/<name>.json``) holds only parameters:
   serve), so their answers sit in the result cache when the window
   sends them, as on a server that has been running.
 * ``warmup`` — the ``seed`` of the warm-up bursts.
-* ``check`` — how many finished requests the reference checks.
+* ``check`` — how many finished requests the reference checks
+  (``sample``); optionally the ``control`` that takes the program's
+  place (``ignore_writes``; by default every closure one level short),
+  and how many acknowledged inserts are read back (``read_back``).
+* ``writes`` (optional) — ``{"per_read": r, "commit_s": c}``: the
+  configuration's update stream (``data["stream"]``, operations in
+  stream order) is sent beside the reads, ``r`` operations per read, in
+  stream order, each due at its stream time mapped onto the window; a
+  writer applies those that fell due in each ``c`` seconds as one batch.
+  Set-up first applies the stream's head as one batch, so that the
+  window's inserts keep the program's insert buffer at one power-of-two
+  size (see :func:`write_plan`).
 
 Arrivals are Poisson in shape: the gaps are the quantiles of an
 exponential distribution at ``rate_qps``, scaled to end inside the
@@ -50,7 +69,11 @@ import numpy as np
 from . import reference as ref
 
 # stream ids: each use of a seed draws from its own stream
-POOL, ORDER, GAPS, WARMUP, CHECK = 0, 1, 2, 4, 5
+POOL, ORDER, GAPS, WARMUP, CHECK, READ_BACK, STRADDLE = 0, 1, 2, 4, 5, 6, 7
+# the overlay's size at which the program compacts it into a new base
+# (``repro.core.delta.DEFAULT_COMPACT_THRESHOLD``): set-up and window
+# stay under it, so no compaction runs
+COMPACT_ROWS = 32768
 
 
 @dataclass
@@ -69,6 +92,98 @@ class Plan:
 
     requests: List[Request]
     cached: List[Request] = field(default_factory=list)
+
+
+@dataclass
+class WritePlan:
+    """The update stream's part in a run: operations ``[0, head)`` are
+    applied in set-up as one batch; ``[head, head + len(due))`` are sent
+    in the window, operation ``head + i`` due at ``due[i]``, and applied
+    ``commit_s`` seconds at a time.  ``cap`` is the insert buffer's
+    power-of-two size through the window; ``asked`` the operations the
+    mix asked for (more than ``len(due)`` only when the stream is too
+    short)."""
+
+    head: int
+    due: np.ndarray
+    commit_s: float
+    cap: int
+    asked: int
+
+    @property
+    def end(self) -> int:
+        return self.head + int(self.due.size)
+
+
+def stream_edges(stream: Dict[str, Any], lo: int, hi: int):
+    """The raw (s, p, o) edges of stream operations ``[lo, hi)``."""
+    a, b = int(stream["off"][lo]), int(stream["off"][hi])
+    return stream["s"][a:b], stream["p"][a:b], stream["o"][a:b]
+
+
+def write_plan(mix: Dict[str, Any], data: Dict[str, Any], seconds: float,
+               rate: Optional[float] = None) -> Optional[WritePlan]:
+    """The run's writes, or ``None`` for a mix without them.
+
+    Each raw insert adds two rows to the program's insert buffer (the
+    edge and its inverse; stream edges are distinct and not in the
+    base), and the program pads the buffer to the next power of two
+    (at least 8).  The head applied in set-up is the shortest one that
+    fills more than half of the smallest such size ``cap`` that head and
+    window fit, so every batch of the window keeps that size and the
+    compiled programs its shape.  Head and window stay under
+    ``COMPACT_ROWS``."""
+    w = mix.get("writes")
+    if not w:
+        return None
+    rate = float(rate if rate is not None else mix["rate_qps"])
+    st = data["stream"]
+    n_ops = int(st["off"].size) - 1
+    cum = 2 * np.asarray(st["off"], dtype=np.int64)    # rows before op i
+    asked = max(1, int(round(rate * float(w["per_read"]) * seconds)))
+    best = None
+    cap = 8
+    while cap // 2 < COMPACT_ROWS:
+        head = int(np.searchsorted(cum, cap // 2, side="right"))
+        if head > n_ops:
+            break
+        room = min(cap, COMPACT_ROWS - 1)
+        n = int(np.searchsorted(cum, room, side="right")) - 1 - head
+        n = max(0, min(asked, n, n_ops - head))
+        if best is None or n > best[2]:
+            best = (cap, head, n)
+        if n == asked:
+            break
+        cap *= 2
+    if best is None or best[2] == 0:
+        raise ValueError("the update stream is too short for the writes "
+                         "this mix asks for")
+    cap, head, n = best
+    t = np.asarray(st["t"], dtype=np.float64)
+    t_end = t[head + n] if head + n < n_ops else None
+    if t_end is not None and t_end > t[head]:
+        due = seconds * (t[head:head + n] - t[head]) / (t_end - t[head])
+    else:
+        due = seconds * np.arange(n) / n
+    return WritePlan(head, due, float(w["commit_s"]), cap, asked)
+
+
+def written_preds(data: Dict[str, Any], wplan: Optional[WritePlan]) -> set:
+    """Predicate ids the window's inserts write."""
+    if wplan is None:
+        return set()
+    return set(np.unique(stream_edges(data["stream"], wplan.head,
+                                      wplan.end)[1]).tolist())
+
+
+def expr_preds(expr: str, data: Dict[str, Any]) -> List[int]:
+    """Predicate ids an expression names (by id, or by name where the
+    configuration names its predicates)."""
+    names = data.get("pred_names")
+    index = {n: i for i, n in enumerate(names)} if names else {}
+    return [index[t] if t in index else int(t)
+            for t in re.findall(r"[A-Za-z0-9_:.-]+", expr)
+            if t in index or t.isdigit()]
 
 
 def rng_for(seed: int, stream: int) -> np.random.Generator:
@@ -98,11 +213,18 @@ def draw_requests(mix: Dict[str, Any], data: Dict[str, Any], n: int,
              if template is None or t["name"] == template]
     w = np.array([t["weight"] for t in temps], dtype=np.float64)
     w /= w.sum()
+    exact = template is None and mix.get("shares") == "exact"
+    sent = np.zeros(len(temps))
     pw = _pred_weights(data["num_preds"])
     cpw = np.cumsum(pw)
     out = []
-    for _ in range(n):
-        t = temps[int(rng.choice(len(temps), p=w))]
+    for i in range(n):
+        if exact:
+            k = int(np.argmax((i + 1) * w - sent))
+            sent[k] += 1
+        else:
+            k = int(rng.choice(len(temps), p=w))
+        t = temps[k]
         preds = [str(int(np.searchsorted(cpw, rng.random(), side="right")
                          .clip(0, data["num_preds"] - 1))) for _ in range(4)]
         expr = t["expr"].format(*preds)
@@ -137,7 +259,7 @@ def deal(keys: Sequence[float], blocks: int,
     return [b[int(j)] for b in out for j in rng.permutation(len(b))]
 
 
-def permute_alike(order: Sequence[int], keys: Sequence[int],
+def permute_alike(order: Sequence[int], keys: Sequence[Any],
                   rng: np.random.Generator) -> List[int]:
     """``order`` with the items of each key permuted among the positions
     that key holds."""
@@ -161,9 +283,13 @@ def window_plan(mix: Dict[str, Any], data: Dict[str, Any], seconds: float,
     cached_at: List[int] = []
     if mix.get("cached"):
         use = np.bincount(data["p"], minlength=data["num_preds"])
-        cost = [sum(int(use[int(x)]) for x in re.findall(r"\d+", r.expr))
-                for r in pool]
-        pick = sorted(range(n), key=lambda i: (cost[i], i))
+        preds = [expr_preds(r.expr, data) for r in pool]
+        cost = [sum(int(use[x]) for x in ps) for ps in preds]
+        # a window's insert invalidates the cached answers over its
+        # predicate: requests over predicates it writes come last
+        written = written_preds(data, write_plan(mix, data, seconds, rate))
+        stale = [bool(written.intersection(ps)) for ps in preds]
+        pick = sorted(range(n), key=lambda i: (stale[i], cost[i], i))
         cached_at = sorted(pick[:int(mix["cached"]["count"])])
     levels = [-1 if i in cached_at else
               ref.levels(graph, r.expr, r.subject, r.obj)
@@ -172,7 +298,10 @@ def window_plan(mix: Dict[str, Any], data: Dict[str, Any], seconds: float,
     order = deal(levels, blocks, rng_for(mix["pool_seed"], ORDER))
     gaps = exp_gaps(n, rate, seconds)
     gaps = gaps[deal(list(gaps), blocks, rng_for(mix["pool_seed"], GAPS))]
-    order = permute_alike(order, levels, rng_for(seed, ORDER))
+    alike: List[Any] = levels
+    if mix["layout"].get("alike") == "template":
+        alike = [(lv, r.template) for lv, r in zip(levels, pool)]
+    order = permute_alike(order, alike, rng_for(seed, ORDER))
     reqs = [pool[i] for i in order]
     for r, d in zip(reqs, np.cumsum(gaps)):
         r.due = float(d)
